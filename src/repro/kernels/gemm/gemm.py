@@ -15,10 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams → CompilerParams in newer jax
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
     k = pl.program_id(2)
@@ -59,7 +55,7 @@ def gemm(a: jax.Array, b: jax.Array, *, bm: int = 256, bn: int = 256,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         # M/N grid axes carry independent output tiles → megacore-parallel;
         # K is the fp32 accumulation and must stay sequential
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

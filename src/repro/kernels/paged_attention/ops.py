@@ -5,7 +5,7 @@ Called per model shard from inside the decode `shard_map`
 shard index, outputs the unnormalized (o, m, l) softmax partials the
 caller feeds to the cross-shard exact `_combine`.
 
-Dispatch (``impl`` arg / `PAGED_KERNEL_BACKEND` env):
+Dispatch (``impl`` arg):
   "auto"      TPU → compiled Pallas kernel; other backends → "ref". The
               Pallas interpreter is an emulator (~50× the fused-XLA cost),
               so it is never a default *serving* path off-TPU.
@@ -19,8 +19,6 @@ Dispatch (``impl`` arg / `PAGED_KERNEL_BACKEND` env):
 """
 from __future__ import annotations
 
-import os
-
 import jax
 
 from repro.kernels.paged_attention import ref
@@ -31,9 +29,8 @@ _IMPLS = ("auto", "kernel", "interpret", "ref")
 
 
 def _resolve(impl: str) -> tuple[str, bool]:
-    """→ (path, interpret) where path ∈ {"kernel", "ref"}. The env override
-    is read per call so it works however late the module was imported."""
-    impl = impl or os.environ.get("PAGED_KERNEL_BACKEND", "auto")
+    """→ (path, interpret) where path ∈ {"kernel", "ref"}; "" is "auto"."""
+    impl = impl or "auto"
     if impl not in _IMPLS:
         raise ValueError(f"paged-attention impl {impl!r}: expected one of "
                          f"{_IMPLS}")

@@ -40,10 +40,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
 
-# renamed TPUCompilerParams → CompilerParams in newer jax
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _online_update(s, ok, acc_ref, m_ref, l_ref, ov):
     """One page block of flash accumulation. s (H, ps) masked scores, ok
@@ -63,10 +59,14 @@ def _online_update(s, ok, acc_ref, m_ref, l_ref, ov):
 def _store_partials(o_ref, m_ref_o, l_ref_o, acc_ref, m_ref, l_ref):
     """Emit the shard-local (o, m, l) partials for the cross-shard combine.
     o stays UNNORMALIZED — `_combine` rescales by exp(m - m_global) and
-    divides by the psum'd l, so fully-masked shards contribute zero."""
+    divides by the psum'd l, so fully-masked shards contribute zero.
+    m/l leave as (B, H, 1) arrays in (1, H, 1) blocks: the last two block
+    dims then equal the array's, which Mosaic requires of a block whose
+    dims are not multiples of (8, 128) — a (1, H) block of (B, H) is
+    refused for B > 1. The wrappers drop the unit axis."""
     o_ref[0] = acc_ref[...]
-    m_ref_o[0] = m_ref[:, 0]
-    l_ref_o[0] = l_ref[:, 0]
+    m_ref_o[0] = m_ref[...]
+    l_ref_o[0] = l_ref[...]
 
 
 def _gqa_kernel(pt_ref, pos_ref, base_ref, q_ref, k_ref, v_ref,
@@ -181,8 +181,8 @@ def paged_flash_decode_gqa(q, pool_k, pool_v, page_table, pos, base, *,
         ],
         out_specs=[
             pl.BlockSpec((1, H, dh), lambda b, t, pt, p, o: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, t, pt, p, o: (b, 0)),
-            pl.BlockSpec((1, H), lambda b, t, pt, p, o: (b, 0)),
+            pl.BlockSpec((1, H, 1), lambda b, t, pt, p, o: (b, 0, 0)),
+            pl.BlockSpec((1, H, 1), lambda b, t, pt, p, o: (b, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((H, dh), jnp.float32),
@@ -192,20 +192,21 @@ def paged_flash_decode_gqa(q, pool_k, pool_v, page_table, pos, base, *,
     )
     kern = functools.partial(_gqa_kernel, page_size=page_size, hkv=hkv,
                              grp=grp, nt=T, softcap=softcap, scale=scale)
-    return pl.pallas_call(
+    o, m, l = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, dh), jnp.float32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
         ],
         # the page axis carries the (acc, m, l) flash state → sequential;
         # batch rows are independent
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*scalars, q, pool_k, pool_v)
+    return o, m[..., 0], l[..., 0]
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "kv_lora", "scale",
@@ -231,8 +232,8 @@ def paged_flash_decode_mla(q, pool, page_table, pos, base, *,
         ],
         out_specs=[
             pl.BlockSpec((1, H, kv_lora), lambda b, t, pt, p, o: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, t, pt, p, o: (b, 0)),
-            pl.BlockSpec((1, H), lambda b, t, pt, p, o: (b, 0)),
+            pl.BlockSpec((1, H, 1), lambda b, t, pt, p, o: (b, 0, 0)),
+            pl.BlockSpec((1, H, 1), lambda b, t, pt, p, o: (b, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((H, kv_lora), jnp.float32),
@@ -242,15 +243,16 @@ def paged_flash_decode_mla(q, pool, page_table, pos, base, *,
     )
     kern = functools.partial(_mla_kernel, page_size=page_size,
                              kv_lora=kv_lora, nt=T, scale=scale)
-    return pl.pallas_call(
+    o, m, l = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, kv_lora), jnp.float32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*scalars, q, pool)
+    return o, m[..., 0], l[..., 0]

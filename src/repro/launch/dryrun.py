@@ -1,7 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede any jax import: jax locks the device count on first init.
 # Never set this globally — smoke tests and benches must see 1 device.
+# CPU only, children included: a dry run must never take a chip.
 
 """Multi-pod dry-run: lower + compile every (arch × shape) cell on the
 production meshes and extract memory / cost / collective analyses.

@@ -8,15 +8,13 @@ device state (smoke tests must keep seeing 1 device).
 """
 from __future__ import annotations
 
-import jax
-
-from repro.sharding.axes import DEFAULT_RULES, ShardCtx
+from repro.sharding.axes import DEFAULT_RULES, ShardCtx, make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_ctx(*, multi_pod: bool = False, rules=None) -> ShardCtx:

@@ -1,12 +1,11 @@
 """Training launcher.
 
-    PYTHONPATH=src python -m repro.launch.train --arch gemma2-2b \
+    PYTHONPATH=src python -m repro.launch.train --arch mistral-nemo-12b \
         --steps 50 --batch 8 --seq 128 [--smoke] [--microbatches 2] \
-        [--compression int8] [--ckpt-dir /tmp/ckpt]
+        [--compression int8] [--ckpt-dir ckpt]
 
-On this CPU container you train reduced (--smoke) configs; on a real slice
-the same entrypoint drives the production mesh (the dry-run proves the full
-configs lower + compile there).
+Without ``--smoke`` the registered config trains at its published widths;
+``--smoke`` swaps in the family-preserving CPU reduction.
 """
 from __future__ import annotations
 
@@ -17,6 +16,7 @@ import jax
 from repro.configs import get_config, smoke_config
 from repro.data.loader import PrefetchLoader
 from repro.data.synthetic import SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.sharding.axes import single_device_ctx
 from repro.train.compression import CompressionConfig
 from repro.train.loop import LoopConfig, train_loop
@@ -30,16 +30,18 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=1e-3)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="train the CPU-sized smoke reduction")
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--compression", choices=["none", "int8", "topk"],
                     default="none")
     ap.add_argument("--moments", choices=["float32", "int8"],
                     default="float32")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default="ckpt")
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

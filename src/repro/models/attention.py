@@ -21,7 +21,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -486,8 +485,9 @@ def cp_gqa_attention(cfg: ModelConfig, p, x, ctx: ShardCtx, *, window: int,
     bp = xspec[0]
     kvspec = P(bp, "model", None, None)
     out_specs = (xspec, kvspec, kvspec) if return_kv else xspec
-    fn = shard_map(local, mesh=mesh, in_specs=(xspec, {n: pspecs[n] for n in p}),
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(xspec, {n: pspecs[n] for n in p}),
+                       out_specs=out_specs, check_vma=False)
     return fn(x, dict(p))
 
 

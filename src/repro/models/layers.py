@@ -113,7 +113,6 @@ def mlp(cfg: ModelConfig, p, x: jax.Array, ctx: ShardCtx,
             h = activation(cfg.act)(h)
         return jnp.einsum("bsf,fd->bsd", h, p["w_down"])
 
-    from jax.experimental.shard_map import shard_map
     xspec = ctx.spec(("batch", "seq", None), x.shape)
     seq_sharded = xspec[1] is not None      # decode S=1 → replicated path
     pspecs = {n: ctx.spec(("embed", "mlp") if n != "w_down" else
@@ -136,9 +135,9 @@ def mlp(cfg: ModelConfig, p, x: jax.Array, ctx: ShardCtx,
                                         tiled=True)
         return jax.lax.psum(out, "model")
 
-    fn = shard_map(local, mesh=ctx.mesh,
-                   in_specs=(xspec, {n: pspecs[n] for n in p}),
-                   out_specs=xspec, check_rep=False)
+    fn = jax.shard_map(local, mesh=ctx.mesh,
+                       in_specs=(xspec, {n: pspecs[n] for n in p}),
+                       out_specs=xspec, check_vma=False)
     return fn(x, dict(p))
 
 

@@ -22,7 +22,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import gate_fn, is_gated, activation
@@ -157,10 +156,10 @@ def moe_block(cfg: ModelConfig, p, x, ctx: ShardCtx):
 
     dp = tuple(a for a in ("pod", "data") if a in mesh.shape)
     stats_spec = P(dp if dp else None, None, None)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(bspec, {n: pspecs[n] for n in p}),
-                   out_specs=(bspec, stats_spec),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(bspec, {n: pspecs[n] for n in p}),
+                       out_specs=(bspec, stats_spec),
+                       check_vma=False)
     out, stats = fn(x, dict(p))
     return out, jnp.mean(stats, axis=0)
 
@@ -216,9 +215,9 @@ def moe_decode(cfg: ModelConfig, p, x, ctx: ShardCtx):
             out = out + jnp.einsum("tf,fd->td", hs, ws_down)
         return jax.lax.psum(out, "model")
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(xspec, {n: pspecs[n] for n in p}),
-                   out_specs=xspec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(xspec, {n: pspecs[n] for n in p}),
+                       out_specs=xspec, check_vma=False)
     return fn(x, dict(p))
 
 

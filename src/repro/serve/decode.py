@@ -30,7 +30,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -175,10 +174,11 @@ def flash_decode_gqa(q, k_new, v_new, ck, cv, pos, *, window: int,
             l = l.reshape(B, hkv, grp)
             return _combine(o, m, l).astype(q.dtype), pk, pv
 
-        fn = shard_map(local_paged, mesh=mesh,
-                       in_specs=(qspec, nspec, nspec, poolspec, poolspec,
-                                 pspec, ptspec),
-                       out_specs=(qspec, poolspec, poolspec), check_rep=False)
+        fn = jax.shard_map(local_paged, mesh=mesh,
+                           in_specs=(qspec, nspec, nspec, poolspec, poolspec,
+                                     pspec, ptspec),
+                           out_specs=(qspec, poolspec, poolspec),
+                           check_vma=False)
         return fn(q, k_new, v_new, ck, cv, pos, page_table)
 
     cspec = ctx.spec(("batch", "kv_seq", "kv_heads", None), ck.shape)
@@ -212,9 +212,9 @@ def flash_decode_gqa(q, k_new, v_new, ck, cv, pos, *, window: int,
         l = jnp.sum(p, -1)
         return _combine(o, m, l).astype(q.dtype), ck, cv
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(qspec, nspec, nspec, cspec, cspec, pspec),
-                   out_specs=(qspec, cspec, cspec), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(qspec, nspec, nspec, cspec, cspec, pspec),
+                       out_specs=(qspec, cspec, cspec), check_vma=False)
     return fn(q, k_new, v_new, ck, cv, pos)
 
 
@@ -245,9 +245,9 @@ def flash_decode_mla(q_eff, new_row, ckv, pos, *, kv_lora: int, scale: float,
                 scale=scale, impl=impl)
             return _combine(o, m, l).astype(q.dtype), pool
 
-        fn = shard_map(local_paged, mesh=mesh,
-                       in_specs=(qspec, nspec, poolspec, pspec, ptspec),
-                       out_specs=(qspec, poolspec), check_rep=False)
+        fn = jax.shard_map(local_paged, mesh=mesh,
+                           in_specs=(qspec, nspec, poolspec, pspec, ptspec),
+                           out_specs=(qspec, poolspec), check_vma=False)
         return fn(q_eff, new_row, ckv, pos, page_table)
 
     cspec = ctx.spec(("batch", "kv_seq", None), ckv.shape)
@@ -272,9 +272,9 @@ def flash_decode_mla(q_eff, new_row, ckv, pos, *, kv_lora: int, scale: float,
 
     _write3 = _local_write
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(qspec, nspec, cspec, pspec),
-                   out_specs=(qspec, cspec), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(qspec, nspec, cspec, pspec),
+                       out_specs=(qspec, cspec), check_vma=False)
     return fn(q_eff, new_row, ckv, pos)
 
 
@@ -580,10 +580,10 @@ def flash_verify_gqa(q, k_new, v_new, ck, cv, pos0, *, window: int,
             out = _combine(*_merge_partials(o, m, l, o2, m2, l2))
             return jnp.moveaxis(out, 3, 1).astype(q.dtype)
 
-        fn = shard_map(local_paged, mesh=mesh,
-                       in_specs=(qspec, nspec, nspec, poolspec, poolspec,
-                                 pspec, ptspec),
-                       out_specs=qspec, check_rep=False)
+        fn = jax.shard_map(local_paged, mesh=mesh,
+                           in_specs=(qspec, nspec, nspec, poolspec, poolspec,
+                                     pspec, ptspec),
+                           out_specs=qspec, check_vma=False)
         return fn(q, k_new, v_new, ck, cv, pos0, page_table)
 
     cspec = ctx.spec(("batch", "kv_seq", "kv_heads", None), ck.shape)
@@ -621,9 +621,9 @@ def flash_verify_gqa(q, k_new, v_new, ck, cv, pos0, *, window: int,
         out = _combine(*_merge_partials(o, m, l, o2, m2, l2))
         return jnp.moveaxis(out, 3, 1).astype(q.dtype)
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(qspec, nspec, nspec, cspec, cspec, pspec),
-                   out_specs=qspec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(qspec, nspec, nspec, cspec, cspec, pspec),
+                       out_specs=qspec, check_vma=False)
     return fn(q, k_new, v_new, ck, cv, pos0)
 
 
@@ -676,9 +676,9 @@ def flash_verify_mla(q_eff, new_rows, ckv, pos0, *, kv_lora: int,
             out = _combine(*_merge_partials(o, m, l, o2, m2, l2))
             return jnp.moveaxis(out, 2, 1).astype(q.dtype)
 
-        fn = shard_map(local_paged, mesh=mesh,
-                       in_specs=(qspec, nspec, poolspec, pspec, ptspec),
-                       out_specs=qspec, check_rep=False)
+        fn = jax.shard_map(local_paged, mesh=mesh,
+                           in_specs=(qspec, nspec, poolspec, pspec, ptspec),
+                           out_specs=qspec, check_vma=False)
         return fn(q_eff, new_rows, ckv, pos0, page_table)
 
     cspec = ctx.spec(("batch", "kv_seq", None), ckv.shape)
@@ -701,9 +701,9 @@ def flash_verify_mla(q_eff, new_rows, ckv, pos0, *, kv_lora: int,
         out = _combine(*_merge_partials(o, m, l, o2, m2, l2))
         return jnp.moveaxis(out, 2, 1).astype(q.dtype)
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(qspec, nspec, cspec, pspec),
-                   out_specs=qspec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(qspec, nspec, cspec, pspec),
+                       out_specs=qspec, check_vma=False)
     return fn(q_eff, new_rows, ckv, pos0)
 
 
@@ -875,9 +875,9 @@ def commit_rows(cache, rows, pos0, n, ctx: ShardCtx, *, window: int = 0,
                 pool = _paged_write(pool, rows[:, j], pt, pos, i, msize)
             return pool
 
-        fn = shard_map(local, mesh=mesh,
-                       in_specs=(poolspec, rspec, ptspec, pspec, pspec),
-                       out_specs=poolspec, check_rep=False)
+        fn = jax.shard_map(local, mesh=mesh,
+                           in_specs=(poolspec, rspec, ptspec, pspec, pspec),
+                           out_specs=poolspec, check_vma=False)
         return fn(cache, rows, page_table, pos0, n)
 
     cspec = ctx.spec(axes, cache.shape)
@@ -893,9 +893,9 @@ def commit_rows(cache, rows, pos0, n, ctx: ShardCtx, *, window: int = 0,
             cache = _local_write(cache, rows[:, j], rel)
         return cache
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(cspec, rspec, pspec, pspec),
-                   out_specs=cspec, check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(cspec, rspec, pspec, pspec),
+                       out_specs=cspec, check_vma=False)
     return fn(cache, rows, pos0, n)
 
 

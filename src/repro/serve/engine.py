@@ -308,9 +308,11 @@ class Engine:
         Args:
           cfg: model config (any decoder-only family; enc-dec audio serves
             through ``whisper_decode_step`` instead).
-          params: parameter tree from ``prm.materialize(model_defs(cfg))``
-            — may be shared (read-only) across several engines, which is
-            how ``MultiEngine`` builds token-equivalent tiers.
+          params: parameter tree of ``model_defs(cfg)`` on ``ctx``'s mesh
+            (``make_engine`` draws it there with
+            ``prm.materialize_sharded``) — may be shared (read-only)
+            across engines on one mesh, which is how ``MultiEngine``
+            builds token-equivalent tiers.
           ctx: sharding context; the KV cache is mesh-placed at init.
           max_slots: decode batch width — concurrent streams.
           max_len: per-slot context capacity (prompt + generated tokens).
@@ -443,6 +445,9 @@ class Engine:
                             for seg in layer_schedule(cfg)
                             for bc in seg.pattern)
         msize = ctx.axis_size("model")
+        # host-made state goes straight to this engine's mesh (one replica
+        # per chip must not route its vectors through the default device)
+        self._repl = NamedSharding(ctx.mesh, PartitionSpec())
         self.paged = bool(paged)
         cache_d = None
         if self.paged:
@@ -470,7 +475,8 @@ class Engine:
             cache_d = paged_cache_defs(cfg, max_slots, max_len, msize,
                                        num_pages=self.num_pages,
                                        page_size=page_size)
-            self.page_table_dev = jnp.asarray(self.alloc.table)
+            self.page_table_dev = jax.device_put(self.alloc.table,
+                                                 self._repl)
             self._table_dirty = False
             self.pos_host = np.zeros(max_slots, np.int64)  # device-pos mirror
         else:
@@ -481,24 +487,19 @@ class Engine:
             cache_d = {"tgt": cache_d,
                        "dft": cache_defs(draft_cfg, max_slots, max_len,
                                          msize)}
-        # place the cache on the mesh up front: the donated decode loop
-        # emits mesh-sharded leaves, and a fresh SingleDeviceSharding cache
-        # would make every admit bucket compile twice (once per sharding).
-        # single-device meshes get the replicated spec the loop actually
-        # emits; real meshes get the defs' kv_seq shardings (replicating a
-        # pool across the model axis would forfeit the HBM the pool saves)
-        self.cache = prm.materialize(cache_d, jax.random.PRNGKey(0))
-        if ctx.mesh.size == 1:
-            self.cache = jax.device_put(
-                self.cache, NamedSharding(ctx.mesh, PartitionSpec()))
-        else:
-            self.cache = jax.tree.map(jax.device_put, self.cache,
-                                      prm.shardings(cache_d, ctx))
+        # the cache is born on the mesh in its final sharding: the donated
+        # decode loop emits mesh-sharded leaves, and a cache placed any
+        # other way would make every admit bucket compile twice (once per
+        # sharding). Real meshes get the defs' kv_seq shardings
+        # (replicating a pool across the model axis would forfeit the HBM
+        # the pool saves)
+        self.cache = prm.materialize_sharded(cache_d, jax.random.PRNGKey(0),
+                                             ctx)
         self.kinds = cache_kinds(cfg, paged=self.paged)
         if self._spec:
             if draft_params is None:
-                draft_params = prm.materialize(model_defs(draft_cfg),
-                                               jax.random.PRNGKey(0))
+                draft_params = prm.materialize_sharded(
+                    model_defs(draft_cfg), jax.random.PRNGKey(0), ctx)
             self.draft_params = draft_params
             self.kinds = {"tgt": self.kinds,
                           "dft": cache_kinds(draft_cfg, paged=False)}
@@ -516,7 +517,7 @@ class Engine:
         self.quanta = 0                                # decode dispatches
         self.prefill_groups = 0                        # prefill dispatches
         # device-resident decode state (fast path), mesh-placed like cache
-        repl = NamedSharding(ctx.mesh, PartitionSpec())
+        repl = self._repl
         self.tokens_dev = jax.device_put(jnp.zeros(max_slots, jnp.int32),
                                          repl)
         self.pos_dev = jax.device_put(jnp.zeros(max_slots, jnp.int32), repl)
@@ -526,7 +527,8 @@ class Engine:
         self.rng_dev = jax.device_put(jax.random.PRNGKey(sample_seed), repl)
         # independent stream for first-token sampling at prefill (split per
         # admitted group on host — a device op, not a blocking fetch)
-        self._prefill_rng = jax.random.PRNGKey(sample_seed + 1)
+        self._prefill_rng = jax.device_put(
+            jax.random.PRNGKey(sample_seed + 1), repl)
         # ---- jitted cells -------------------------------------------------
         self._decode = jax.jit(
             lambda p, c, t, pos: decode_step(cfg, p, c, t, pos, ctx))
@@ -669,6 +671,18 @@ class Engine:
         """Persistently reserved KV-cache HBM (pool + dense leaves)."""
         return sum(int(x.nbytes) for x in jax.tree.leaves(self.cache))
 
+    def decode_hlo(self) -> str:
+        """Compiled text of the decode-quantum program at the full page
+        table width: shows which kernels the device runs (a Pallas kernel
+        appears as ``tpu_custom_call``). Lowers without running or
+        donating anything."""
+        args = (self._loop_params, self.cache, self.tokens_dev,
+                self.pos_dev, self.active_dev, self.remaining_dev,
+                self.rng_dev)
+        if self.paged:
+            args += (self.page_table_dev,)
+        return self._decode_loop.lower(*args).compile().as_text()
+
     # ---- tier-facing interface (submit / step / drain) -------------------
     # MultiEngine treats an Engine as one resource of the paper's CC/FC
     # pool: it probes capacity, hands over queued requests, steps it, and
@@ -759,11 +773,10 @@ class Engine:
         if self.paged:
             self._push_page_table()
         if self.fast:
-            repl = NamedSharding(self.ctx.mesh, PartitionSpec())
             self.active_dev = jax.device_put(
-                jnp.zeros(self.max_slots, bool), repl)
+                jnp.zeros(self.max_slots, bool), self._repl)
             self.remaining_dev = jax.device_put(
-                jnp.zeros(self.max_slots, jnp.int32), repl)
+                jnp.zeros(self.max_slots, jnp.int32), self._repl)
         return out
 
     # ---- paged-pool bookkeeping ------------------------------------------
@@ -797,7 +810,8 @@ class Engine:
 
     def _push_page_table(self) -> None:
         if self._table_dirty:
-            self.page_table_dev = jnp.asarray(self.alloc.table)
+            self.page_table_dev = jax.device_put(self.alloc.table,
+                                                 self._repl)
             self._table_dirty = False
 
     def _live_page_table(self, active_slots: list[int]):
@@ -1114,5 +1128,8 @@ class Engine:
 
 def make_engine(cfg: ModelConfig, ctx: ShardCtx, seed: int = 0,
                 **kw) -> Engine:
-    params = prm.materialize(model_defs(cfg), jax.random.PRNGKey(seed))
+    """Engine over seeded random weights, born on ``ctx``'s mesh in their
+    final sharding by one jitted init (no eager per-leaf float32 copy)."""
+    params = prm.materialize_sharded(model_defs(cfg),
+                                     jax.random.PRNGKey(seed), ctx)
     return Engine(cfg, params, ctx, **kw)

@@ -30,8 +30,10 @@ blocked behind a dead tier.
 
 Tiers with ``concurrent=True`` (default) step in parallel threads — the
 serving analogue of the paper's resources running simultaneously; each
-engine is only ever touched by one thread per cycle, engines share the
-(read-only) parameter tree, and the shared tracker is lock-guarded. At
+engine is only ever touched by one thread per cycle, engines on one mesh
+share the (read-only) parameter tree, and the shared tracker is
+lock-guarded. A tier may have a mesh of its own — one replica per chip —
+and then holds its own copy of the same weights. At
 ``temperature=0`` every tier built over the same parameters decodes the
 same greedy stream, so a request's output is independent of the tier that
 served it (asserted by ``tests/test_multi_engine.py`` and BENCH_3).
@@ -760,19 +762,27 @@ def make_multi_engine(cfg: ModelConfig, ctx: ShardCtx,
                       concurrent: bool = True,
                       policy: HealthPolicy | None = None,
                       **shared_kw) -> MultiEngine:
-    """Build a tier pool over ONE shared parameter set.
+    """Build a tier pool over ONE seeded parameter set.
 
     Each dict in ``tier_kws`` holds that tier's Engine kwargs plus the
     optional routing keys ``name`` / ``kind`` / ``unit_cost`` /
-    ``prior_tok_s``; ``shared_kw`` is merged under every tier (tier keys
-    win). Sharing the materialized parameters is what makes the tiers
-    token-equivalent at ``temperature=0`` — and costs one copy of the
-    model, not N.
+    ``prior_tok_s`` and an optional ``ctx`` (default: the ``ctx``
+    argument); ``shared_kw`` is merged under every tier (tier keys win).
+    Tiers on one mesh share one copy of the parameters, born on the first
+    tier's mesh; each other mesh gets a device-to-device copy of it.
+    Identical weights are what make the tiers token-equivalent at
+    ``temperature=0``.
 
         meng = make_multi_engine(cfg, ctx, [
             {"name": "dense"},
             {"name": "paged", "paged": True, "page_size": 8},
         ], max_slots=4, max_len=128)
+
+    A replica per chip gives each tier its own one-device mesh:
+
+        meng = make_multi_engine(cfg, ctx, [
+            {"name": f"chip{i}", "ctx": single_device_ctx(d)}
+            for i, d in enumerate(jax.devices())], paged=True)
 
     A big/little speculative tier rides the same mechanism — pass that
     tier ``draft_cfg``/``draft_params``/``spec_k`` in its dict; at
@@ -780,7 +790,8 @@ def make_multi_engine(cfg: ModelConfig, ctx: ShardCtx,
     (greedy spec-decode equivalence, DESIGN.md §7), so pool outputs stay
     tier-independent.
     """
-    params = prm.materialize(model_defs(cfg), jax.random.PRNGKey(seed))
+    defs = model_defs(cfg)
+    params_on: dict = {}                # mesh → that mesh's parameter copy
     tiers = []
     for i, kw in enumerate(tier_kws):
         kw = {**shared_kw, **kw}
@@ -788,7 +799,15 @@ def make_multi_engine(cfg: ModelConfig, ctx: ShardCtx,
         kind = kw.pop("kind", "core")
         unit_cost = kw.pop("unit_cost", 1.0)
         prior = kw.pop("prior_tok_s", 1.0)
-        tiers.append(EngineTier(name, Engine(cfg, params, ctx, **kw),
+        tctx = kw.pop("ctx", ctx)
+        if not params_on:
+            params_on[tctx.mesh] = prm.materialize_sharded(
+                defs, jax.random.PRNGKey(seed), tctx)
+        elif tctx.mesh not in params_on:     # copy, device to device
+            params_on[tctx.mesh] = jax.device_put(
+                next(iter(params_on.values())), prm.shardings(defs, tctx))
+        params = params_on[tctx.mesh]
+        tiers.append(EngineTier(name, Engine(cfg, params, tctx, **kw),
                                 kind=kind, unit_cost=unit_cost,
                                 prior_tok_s=prior))
     return MultiEngine(tiers, concurrent=concurrent, policy=policy)

@@ -17,7 +17,7 @@ import math
 from typing import Mapping, Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # Logical axis vocabulary (see DESIGN.md §3):
 #   batch     global batch                     → (pod, data)
@@ -80,6 +80,10 @@ class ShardCtx:
         return logical_to_spec(axes, shape, self.mesh, self.rules)
 
     def sharding(self, axes: Sequence[str | None], shape: Sequence[int]) -> NamedSharding:
+        if self.mesh.size == 1:
+            # the spec a jitted step emits on one device: arrays placed
+            # here and a step's outputs then share one compile
+            return NamedSharding(self.mesh, P())
         return NamedSharding(self.mesh, self.spec(axes, shape))
 
     def constrain(self, x: jax.Array, axes: Sequence[str | None]) -> jax.Array:
@@ -88,11 +92,22 @@ class ShardCtx:
         return jax.lax.with_sharding_constraint(x, self.sharding(axes, x.shape))
 
 
-def single_device_ctx() -> ShardCtx:
-    """1-device mesh with the production axis names — used by smoke tests."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
-    return ShardCtx(mesh=mesh)
+def make_mesh(shape: Sequence[int], names: Sequence[str],
+              devices=None) -> Mesh:
+    """The one place meshes are built. Axes are ``Auto`` so GSPMD
+    propagates shardings and ``ShardCtx.constrain`` may use
+    ``with_sharding_constraint`` (``jax.make_mesh`` defaults to
+    ``Explicit`` axes, which reject both)."""
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
+
+
+def single_device_ctx(device=None) -> ShardCtx:
+    """1-device mesh with the production axis names on ``device`` (default
+    the first device) — one serving replica or a smoke test."""
+    device = device if device is not None else jax.devices()[0]
+    return ShardCtx(mesh=make_mesh((1, 1), ("data", "model"), [device]))
 
 
 def mesh_axis_size(mesh: Mesh, names: Sequence[str]) -> int:

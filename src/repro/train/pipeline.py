@@ -18,7 +18,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -74,7 +73,7 @@ def pipeline_apply(mesh: Mesh, layer_fn, params_stacked, x_mb, *,
         return out
 
     pspec = jax.tree.map(lambda _: P(axis), params_stacked)
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(axis), P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(axis), P()), out_specs=P(),
+                       check_vma=False)
     return fn(params_stacked, x_mb)

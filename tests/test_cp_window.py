@@ -12,18 +12,18 @@ CP = textwrap.dedent("""
     from repro.configs import all_configs, smoke_config
     from repro.models.model import model_defs, loss_fn, synth_batch
     from repro.sharding import params as prm
-    from repro.sharding.axes import ShardCtx
+    from repro.sharding.axes import ShardCtx, make_mesh
 
     cfg = smoke_config(all_configs()["h2o-danube-1.8b"])  # window 32
     params = prm.materialize(model_defs(cfg), jax.random.PRNGKey(0))
     batch = synth_batch(cfg, 4, 64, jax.random.PRNGKey(1))
 
     # single device reference
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    mesh1 = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
     ref = float(loss_fn(cfg, params, batch, ShardCtx(mesh=mesh1))[0])
 
     # 4-way model mesh: S_loc=16, window=32 → n_nb=2 < msize-1 → neighbor path
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ShardCtx(mesh=mesh)
     with mesh:
         got = float(jax.jit(lambda p, b: loss_fn(cfg, p, b, ctx)[0])(params, batch))

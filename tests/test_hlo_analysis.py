@@ -63,7 +63,8 @@ E2E = textwrap.dedent("""
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P, NamedSharding
     from repro.launch.hlo_analysis import analyze_hlo
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.sharding.axes import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     D, F, L, B = 64, 128, 5, 16
     def model(params, x):
         def body(h, w):

@@ -1,6 +1,11 @@
 """Multi-engine heterogeneous serving tiers: routing law, work-conserving
 rebalancing, stall/pool backpressure rerouting, and multi-tier ≡
 single-engine token equivalence at temperature=0."""
+import os
+import subprocess
+import sys
+import textwrap
+
 import numpy as np
 import pytest
 
@@ -255,3 +260,57 @@ def test_multi_engine_stall_reports_per_tier(ctx):
     meng.tiers[0].engine.step = lambda: StepReport()    # hung device
     with pytest.raises(EngineStallError, match="only:"):
         meng.run([Request(rid=1, prompt=[4, 5], max_new=2)])
+
+
+_PER_DEVICE_POOL = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax
+    import numpy as np
+    from repro.configs import all_configs, smoke_config
+    from repro.serve.engine import Engine, Request
+    from repro.serve.multi_engine import make_multi_engine
+    from repro.sharding.axes import single_device_ctx
+
+    cfg = smoke_config(all_configs()["mistral-nemo-12b"])
+    devs = jax.devices()
+    assert len(devs) == 4, devs
+    ctxs = [single_device_ctx(d) for d in devs]
+    kw = dict(max_slots=2, max_len=64, decode_quantum=4, paged=True,
+              page_size=8)
+    meng = make_multi_engine(cfg, ctxs[0], [
+        {"name": f"dev{i}", "ctx": c} for i, c in enumerate(ctxs)], **kw)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab, int(n)).tolist()
+               for n in rng.integers(4, 40, 8)]
+    multi = [Request(rid=i, prompt=p, max_new=6)
+             for i, p in enumerate(prompts)]
+    meng.run(multi)
+    for i, tier in enumerate(meng.tiers):
+        leaves = (jax.tree.leaves(tier.engine.params)
+                  + jax.tree.leaves(tier.engine.cache)
+                  + [tier.engine.page_table_dev, tier.engine.tokens_dev])
+        assert all(x.devices() == {devs[i]} for x in leaves), tier.name
+        assert tier.routed > 0, meng.stats()
+    # one replica on device 0, sharing tier 0's weights
+    single = Engine(cfg, meng.tiers[0].engine.params, ctxs[0], **kw)
+    ref = [Request(rid=i, prompt=p, max_new=6)
+           for i, p in enumerate(prompts)]
+    single.run(ref)
+    for a, b in zip(multi, ref):
+        assert a.done and a.out == b.out, (a.rid, a.out, b.out)
+    print("PER-DEVICE-POOL-OK")
+""")
+
+
+def test_per_device_replica_pool_matches_single_replica():
+    """Four one-device replicas (tier i's weights, cache and state on
+    device i) serve greedy streams identical to one replica; every tier
+    takes work. Four host devices stand in for four chips."""
+    r = subprocess.run([sys.executable, "-c", _PER_DEVICE_POOL],
+                       env=dict(os.environ, PYTHONPATH="src",
+                                JAX_PLATFORMS="cpu"),
+                       capture_output=True, text=True, timeout=600,
+                       cwd=os.path.dirname(os.path.dirname(__file__)))
+    assert "PER-DEVICE-POOL-OK" in r.stdout, \
+        r.stdout[-2000:] + r.stderr[-2000:]

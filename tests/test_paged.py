@@ -154,7 +154,7 @@ _SHARDED = textwrap.dedent("""
     import jax, numpy as np
     from repro.configs import all_configs, smoke_config
     from repro.serve.engine import Request, make_engine
-    from repro.sharding.axes import ShardCtx
+    from repro.sharding.axes import ShardCtx, make_mesh
 
     cfg = smoke_config(all_configs()["mistral-nemo-12b"])
     rng = np.random.default_rng(2)
@@ -172,7 +172,7 @@ _SHARDED = textwrap.dedent("""
     # reductions already reorder vs 1-device (greedy argmax amplifies
     # that, dense path included), so the paging invariant is paged ≡
     # dense at identical sharding
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = make_mesh((1, 4), ("data", "model"))
     ref = serve(ShardCtx(mesh=mesh))
     # 4-way model axis: page_size 8 → each shard owns 2 offsets per page
     got = serve(ShardCtx(mesh=mesh), paged=True, page_size=8)
@@ -201,9 +201,9 @@ def test_paged_rejects_data_parallel_mesh():
         import jax
         from repro.configs import all_configs, smoke_config
         from repro.serve.engine import make_engine
-        from repro.sharding.axes import ShardCtx
+        from repro.sharding.axes import ShardCtx, make_mesh
         cfg = smoke_config(all_configs()["mistral-nemo-12b"])
-        ctx = ShardCtx(mesh=jax.make_mesh((2, 4), ("data", "model")))
+        ctx = ShardCtx(mesh=make_mesh((2, 4), ("data", "model")))
         try:
             make_engine(cfg, ctx, max_len=64, paged=True, page_size=8)
         except ValueError as e:

@@ -229,9 +229,9 @@ _SHARDED = textwrap.dedent("""
     from repro.configs import all_configs, smoke_config
     from repro.serve.decode import flash_decode_gqa
     from repro.serve.engine import Request, make_engine
-    from repro.sharding.axes import ShardCtx
+    from repro.sharding.axes import ShardCtx, make_mesh
 
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = make_mesh((1, 4), ("data", "model"))
     ctx = ShardCtx(mesh=mesh)
 
     # direct kernel-vs-gather on the sharded pool (interpret kernel): each

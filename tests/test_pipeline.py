@@ -17,9 +17,10 @@ PIPE = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.sharding.axes import make_mesh
     from repro.train.pipeline import pipeline_apply
     S, M, mb, D = 4, 8, 2, 16
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = make_mesh((4,), ("stage",))
     key = jax.random.PRNGKey(0)
     Ws = jax.random.normal(key, (S, D, D)) * 0.3
     x = jax.random.normal(jax.random.PRNGKey(1), (M, mb, D))

@@ -269,3 +269,15 @@ def test_sampling_engine_validation(ctx):
         make_engine(cfg, ctx, top_k=cfg.vocab + 1)  # not a lax.top_k trace
     with pytest.raises(ValueError):               # legacy path is greedy
         make_engine(cfg, ctx, fast=False, temperature=0.5)
+
+
+def test_serve_launcher_smoke(monkeypatch):
+    """The serving launcher, as chip_smoke.py drives it, at CPU size:
+    paged engine, every request finished with its full budget."""
+    from repro.launch import serve
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    eng, reqs = serve.main(["--smoke", "--requests", "3", "--max-new", "5"])
+    assert eng.paged and eng.cfg.name.endswith("-smoke")
+    assert all(r.done and len(r.out) == 5 for r in reqs)
+    assert all(serve.PROMPT_LEN[0] <= len(r.prompt) <= serve.PROMPT_LEN[1]
+               for r in reqs)

@@ -117,12 +117,12 @@ SHARDED_TRAIN = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import all_configs, smoke_config
-    from repro.sharding.axes import ShardCtx
+    from repro.sharding.axes import ShardCtx, make_mesh
     from repro.train.step import init_state, make_train_step
     from repro.train.optimizer import OptConfig
     from repro.models.model import synth_batch
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     ctx = ShardCtx(mesh=mesh)
     cfg = smoke_config(all_configs()["phi3.5-moe-42b-a6.6b"])
     ocfg = OptConfig(lr=1e-3)
