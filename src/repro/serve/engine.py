@@ -75,6 +75,7 @@ from repro.models.transformer import layer_schedule
 from repro.serve.decode import _sample_tokens, decode_loop_fn, decode_step
 from repro.serve.kv_cache import cache_defs, cache_kinds, paged_cache_defs
 from repro.serve.prefill import bucket_len, prefill
+from repro.serve.telemetry import span
 from repro.sharding import params as prm
 from repro.sharding.axes import ShardCtx, mesh_axis_size
 
@@ -150,12 +151,21 @@ class Request:
         context limit, whichever comes first.
       out: generated token ids, appended as quanta complete.
       done: set by the engine when the stream is finished.
+      t_submit: ``time.perf_counter()`` at the first ``submit()`` to an
+        ``Engine`` or a ``MultiEngine``; a rerouted or retried request
+        keeps it.
+      t_admit: when an engine's admission first took it from ``pending``.
+      t_first: when its first token first reached the host.
+        Each stamp is ``None`` until set.
     """
     rid: int
     prompt: list[int]
     max_new: int = 16
     out: list[int] = field(default_factory=list)
     done: bool = False
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
 
 
 @dataclass
@@ -512,10 +522,13 @@ class Engine:
         self.pending: list[Request] = []
         self.tracker = ThroughputTracker(
             {"decode": "accelerator", "prefill": "core"}, f0=2.0)
-        self.cycle_log: list[dict] = []                # per-cycle balance
         self._last_admitted = 0
         self.quanta = 0                                # decode dispatches
         self.prefill_groups = 0                        # prefill dispatches
+        # summed engine.prefill spans and the prompt tokens they admitted,
+        # compiling groups included (the tracker's f-ratio skips those)
+        self.prefill_s = 0.0
+        self.prefill_tokens = 0
         # device-resident decode state (fast path), mesh-placed like cache
         repl = self._repl
         self.tokens_dev = jax.device_put(jnp.zeros(max_slots, jnp.int32),
@@ -657,6 +670,8 @@ class Engine:
             raise PromptTooLongError(
                 f"request {req.rid}: prompt of {n} tokens needs at least "
                 f"one decode slot; engine max_len is {self.max_len}")
+        if req.t_submit is None:
+            req.t_submit = time.perf_counter()
         self.pending.append(req)
 
     def free_slots(self) -> list[int]:
@@ -847,32 +862,36 @@ class Engine:
         self._last_admitted = 0
         free = self.free_slots()
         if self.pending and free:
-            self._admit_pending(free)
+            with span("engine.admit"):
+                self._admit_pending(free)
         active_slots = [i for i, r in enumerate(self.slot_req)
                         if r is not None]
         if not active_slots:
-            if self._last_admitted:   # everything finished at prefill —
-                self.cycle_log.append({"admitted": self._last_admitted,
-                                       "decoded": 0,
-                                       "f": self.tracker.f()})
             return StepReport(admitted=self._last_admitted)
-        if self.paged:
-            self._grant_quantum_pages(active_slots)
-            self._push_page_table()
-        t0 = time.perf_counter()
-        n0 = _jit_cache_size(self._decode_loop)
         args = (self._loop_params, self.cache, self.tokens_dev,
                 self.pos_dev, self.active_dev, self.remaining_dev,
                 self.rng_dev)
         if self.paged:
-            carry, packed = self._decode_loop(
-                *args, self._live_page_table(active_slots))
-        else:
+            with span("engine.pages"):
+                self._grant_quantum_pages(active_slots)
+                self._push_page_table()
+                args += (self._live_page_table(active_slots),)
+        t0 = time.perf_counter()
+        n0 = _jit_cache_size(self._decode_loop)
+        with span("engine.decode"):
             carry, packed = self._decode_loop(*args)
-        (self.cache, self.tokens_dev, self.pos_dev, self.active_dev,
-         self.remaining_dev, self.rng_dev) = carry
-        packed_h = _host_fetch(packed)         # the ONE host sync per quantum
+            (self.cache, self.tokens_dev, self.pos_dev, self.active_dev,
+             self.remaining_dev, self.rng_dev) = carry
+        with span("engine.fetch"):
+            packed_h = _host_fetch(packed)     # the ONE host sync per quantum
         dt = time.perf_counter() - t0
+        with span("engine.retire"):
+            return self._retire(active_slots, packed_h, dt, n0)
+
+    def _retire(self, active_slots: list[int], packed_h: np.ndarray,
+                dt: float, n0: int) -> StepReport:
+        """Hand the quantum's tokens to their requests and free the slots
+        whose streams ended."""
         self.quanta += 1
         N = self.decode_quantum
         # a speculative round can emit up to tokens_per_step tokens, so the
@@ -914,8 +933,6 @@ class Engine:
                 self.slot_req[i] = None
                 if self.paged:
                     self._release_slot_pages(i)
-        self.cycle_log.append({"admitted": self._last_admitted,
-                               "decoded": emitted, "f": self.tracker.f()})
         return StepReport(admitted=self._last_admitted, decoded=emitted,
                           dt=dt, warm=warm, accepted=accepted,
                           proposed=proposed)
@@ -945,6 +962,10 @@ class Engine:
             take.append(self.pending.pop(0))
         if not take:
             return
+        now = time.perf_counter()
+        for req in take:
+            if req.t_admit is None:
+                req.t_admit = now
         self._last_admitted = len(take)
         groups: dict[int, list[Request]] = {}
         for req in take:
@@ -992,27 +1013,34 @@ class Engine:
             # step() pushes the updated table to device before the next
             # decode quantum; the admit scatter itself reads page_src only
             extra = (jnp.asarray(self._alloc_group_pages(Sb, reqs, slots)),)
-        t0 = time.perf_counter()
-        p0 = _jit_cache_size(self._prefill_fast)
-        a0 = _jit_cache_size(self._admit)
-        self._prefill_rng, sub = jax.random.split(self._prefill_rng)
-        first, new_cache = self._prefill_fast(self._loop_params,
-                                              jnp.asarray(toks),
-                                              jnp.asarray(pl), sub)
-        (self.cache, self.tokens_dev, self.pos_dev, self.active_dev,
-         self.remaining_dev) = self._admit(
-            self.cache, self.tokens_dev, self.pos_dev, self.active_dev,
-            self.remaining_dev, new_cache, first, jnp.asarray(pl),
-            jnp.asarray(mn), jnp.asarray(slots), jnp.asarray(valid), *extra)
-        jax.block_until_ready((first, self.tokens_dev))
-        dt = time.perf_counter() - t0
-        # probe unavailable (-1 sentinel) → treat as warm and record
-        warm = (p0 < 0 or a0 < 0
-                or (_jit_cache_size(self._prefill_fast) == p0
-                    and _jit_cache_size(self._admit) == a0))
-        self.prefill_groups += 1
-        first_h = _host_fetch(first)           # one sync per admitted group
+        with span("engine.prefill"):
+            t0 = time.perf_counter()
+            p0 = _jit_cache_size(self._prefill_fast)
+            a0 = _jit_cache_size(self._admit)
+            self._prefill_rng, sub = jax.random.split(self._prefill_rng)
+            first, new_cache = self._prefill_fast(self._loop_params,
+                                                  jnp.asarray(toks),
+                                                  jnp.asarray(pl), sub)
+            (self.cache, self.tokens_dev, self.pos_dev, self.active_dev,
+             self.remaining_dev) = self._admit(
+                self.cache, self.tokens_dev, self.pos_dev, self.active_dev,
+                self.remaining_dev, new_cache, first, jnp.asarray(pl),
+                jnp.asarray(mn), jnp.asarray(slots), jnp.asarray(valid),
+                *extra)
+            jax.block_until_ready((first, self.tokens_dev))
+            dt = time.perf_counter() - t0
+            # probe unavailable (-1 sentinel) → treat as warm and record
+            warm = (p0 < 0 or a0 < 0
+                    or (_jit_cache_size(self._prefill_fast) == p0
+                        and _jit_cache_size(self._admit) == a0))
+            self.prefill_groups += 1
+            first_h = _host_fetch(first)       # one sync per admitted group
+            t1 = time.perf_counter()
+        self.prefill_s += t1 - t0
+        self.prefill_tokens += int(pl[:len(reqs)].sum())
         for j, req in enumerate(reqs):
+            if req.t_first is None:
+                req.t_first = t1
             req.out.append(int(first_h[j]))
             if req.max_new <= 1:
                 req.done = True                # budget spent at prefill
@@ -1095,8 +1123,6 @@ class Engine:
                     or self.pos[i] >= self.max_len - 1):
                 req.done = True
                 self.slot_req[i] = None
-        self.cycle_log.append({"admitted": admitted, "decoded": len(active),
-                               "f": self.tracker.f()})
         return StepReport(admitted=admitted, decoded=len(active), dt=dt,
                           warm=warm)
 

@@ -263,6 +263,8 @@ class MultiEngine:
                 f"Request object is single-use until it terminates")
         self.dead_letters.pop(req.rid, None)   # resubmission clears it
         self._resume.pop(req.rid, None)        # and any stale retry state
+        if req.t_submit is None:
+            req.t_submit = time.perf_counter()
         self.queue.append(req)
 
     def has_work(self) -> bool:
