@@ -19,14 +19,19 @@ reserved trash page 0 that absorbs inactive-slot scribbles — cost no
 FLOPs), and the tail page is position-masked. The kernel runs *per model
 shard* inside the decode `shard_map`, so it returns **unnormalized**
 `(o, m, l)` partials; the caller's exact-softmax `_combine` across the
-``model`` axis is unchanged. The in-page write of the new token's K/V
-stays a separate masked scatter outside the kernel (`_paged_write`): a
-scatter through the table is one tiny row per slot — doing it in-kernel
-would force the pool to be an aliased in/out operand for no bandwidth win.
+``model`` axis is unchanged.
+
+The pool operand is the whole layer stack `(L, N, ps, …)` and the layer
+is a fourth scalar-prefetch operand: the index map addresses page
+`(layer, pt[b, t])` of the stack. The decode layer scan carries the
+stacked pool and writes each layer's new rows into it in place
+(`serve/decode.py::_stacked_write`, a B-row scatter before this kernel
+reads), so no layer's pool is ever sliced out of the stack or copied
+back. A one-layer pool `(N, ps, …)` is taken as a stack of one.
 
 Layouts (per shard; ``ps`` = page_size // msize, ``base`` = shard·ps):
-  GQA: q (B, Hkv, G, dh); pools (N, ps, Hkv, dh) ×2 → o (B, Hkv·G, dh).
-  MLA: q (B, H, R);       pool  (N, ps, R)          → o (B, H, kv_lora)
+  GQA: q (B, Hkv, G, dh); pools (L, N, ps, Hkv, dh) ×2 → o (B, Hkv·G, dh).
+  MLA: q (B, H, R);       pool  (L, N, ps, R)          → o (B, H, kv_lora)
        (the cache row is both key and value — MQA-style absorbed MLA).
 """
 from __future__ import annotations
@@ -69,13 +74,13 @@ def _store_partials(o_ref, m_ref_o, l_ref_o, acc_ref, m_ref, l_ref):
     l_ref_o[0] = l_ref[...]
 
 
-def _gqa_kernel(pt_ref, pos_ref, base_ref, q_ref, k_ref, v_ref,
+def _gqa_kernel(pt_ref, pos_ref, base_ref, layer_ref, q_ref, k_ref, v_ref,
                 o_ref, m_out, l_out, acc_ref, m_ref, l_ref, *,
                 page_size: int, hkv: int, grp: int, nt: int, softcap: float,
                 scale: float):
     b = pl.program_id(0)
     t = pl.program_id(1)
-    ps = k_ref.shape[1]                                    # per-shard offsets
+    ps = k_ref.shape[2]                                    # per-shard offsets
     H = hkv * grp
 
     @pl.when(t == 0)
@@ -90,8 +95,8 @@ def _gqa_kernel(pt_ref, pos_ref, base_ref, q_ref, k_ref, v_ref,
     @pl.when(first <= pos)
     def _block():
         q = q_ref[0].astype(jnp.float32) * scale           # (Hkv, G, dh)
-        k = k_ref[0].astype(jnp.float32)                   # (ps, Hkv, dh)
-        v = v_ref[0].astype(jnp.float32)                   # (ps, Hkv, dh)
+        k = k_ref[0, 0].astype(jnp.float32)                # (ps, Hkv, dh)
+        v = v_ref[0, 0].astype(jnp.float32)                # (ps, Hkv, dh)
         # per-kv-head 2D dots (static unroll — Hkv is a config constant)
         s = jnp.concatenate(
             [jax.lax.dot_general(q[h], k[:, h], (((1,), (1,)), ((), ())),
@@ -117,12 +122,12 @@ def _gqa_kernel(pt_ref, pos_ref, base_ref, q_ref, k_ref, v_ref,
         _store_partials(o_ref, m_out, l_out, acc_ref, m_ref, l_ref)
 
 
-def _mla_kernel(pt_ref, pos_ref, base_ref, q_ref, c_ref,
+def _mla_kernel(pt_ref, pos_ref, base_ref, layer_ref, q_ref, c_ref,
                 o_ref, m_out, l_out, acc_ref, m_ref, l_ref, *,
                 page_size: int, kv_lora: int, nt: int, scale: float):
     b = pl.program_id(0)
     t = pl.program_id(1)
-    ps = c_ref.shape[1]
+    ps = c_ref.shape[2]
     H = q_ref.shape[1]
 
     @pl.when(t == 0)
@@ -137,7 +142,7 @@ def _mla_kernel(pt_ref, pos_ref, base_ref, q_ref, c_ref,
     @pl.when(first <= pos)
     def _block():
         q = q_ref[0].astype(jnp.float32) * scale           # (H, R)
-        c = c_ref[0].astype(jnp.float32)                   # (ps, R)
+        c = c_ref[0, 0].astype(jnp.float32)                # (ps, R)
         s = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         gpos = first + jax.lax.broadcasted_iota(jnp.int32, (H, ps), 1)
@@ -156,33 +161,43 @@ def _mla_kernel(pt_ref, pos_ref, base_ref, q_ref, c_ref,
         _store_partials(o_ref, m_out, l_out, acc_ref, m_ref, l_ref)
 
 
+def _scalars(page_table, pos, base, layer):
+    return (page_table.astype(jnp.int32), pos.astype(jnp.int32),
+            jnp.asarray(base, jnp.int32).reshape(1),
+            jnp.asarray(layer, jnp.int32).reshape(1))
+
+
 @functools.partial(jax.jit, static_argnames=("page_size", "scale", "softcap",
                                              "interpret"))
-def paged_flash_decode_gqa(q, pool_k, pool_v, page_table, pos, base, *,
-                           page_size: int, scale: float, softcap: float = 0.0,
-                           interpret: bool = False):
-    """q (B,Hkv,G,dh); pools (N, ps, Hkv, dh); page_table (B, T) int32;
-    pos (B,) int32; base () int32 shard offset (shard_idx · ps).
+def paged_flash_decode_gqa(q, pool_k, pool_v, page_table, pos, base, layer=0,
+                           *, page_size: int, scale: float,
+                           softcap: float = 0.0, interpret: bool = False):
+    """q (B,Hkv,G,dh); pools (L, N, ps, Hkv, dh), or one layer's
+    (N, ps, Hkv, dh); page_table (B, T) int32; pos (B,) int32; base ()
+    int32 shard offset (shard_idx · ps); layer () int32 index into L.
     → unnormalized partials o (B, Hkv·G, dh) f32, m/l (B, Hkv·G) f32."""
+    if pool_k.ndim == 4:
+        pool_k, pool_v = pool_k[None], pool_v[None]
     B, hkv, grp, dh = q.shape
-    ps = pool_k.shape[1]
+    ps = pool_k.shape[2]
     T = page_table.shape[1]
     H = hkv * grp
     grid = (B, T)
-    scalars = (page_table.astype(jnp.int32), pos.astype(jnp.int32),
-               jnp.asarray(base, jnp.int32).reshape(1))
+    page = lambda b, t, pt, p, o, ly: (ly[0], pt[b, t], 0, 0, 0)  # noqa
+    row = lambda b, t, pt, p, o, ly: (b, 0, 0)                    # noqa
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, hkv, grp, dh), lambda b, t, pt, p, o: (b, 0, 0, 0)),
-            pl.BlockSpec((1, ps, hkv, dh), lambda b, t, pt, p, o: (pt[b, t], 0, 0, 0)),
-            pl.BlockSpec((1, ps, hkv, dh), lambda b, t, pt, p, o: (pt[b, t], 0, 0, 0)),
+            pl.BlockSpec((1, hkv, grp, dh),
+                         lambda b, t, pt, p, o, ly: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, ps, hkv, dh), page),
+            pl.BlockSpec((1, 1, ps, hkv, dh), page),
         ],
         out_specs=[
-            pl.BlockSpec((1, H, dh), lambda b, t, pt, p, o: (b, 0, 0)),
-            pl.BlockSpec((1, H, 1), lambda b, t, pt, p, o: (b, 0, 0)),
-            pl.BlockSpec((1, H, 1), lambda b, t, pt, p, o: (b, 0, 0)),
+            pl.BlockSpec((1, H, dh), row),
+            pl.BlockSpec((1, H, 1), row),
+            pl.BlockSpec((1, H, 1), row),
         ],
         scratch_shapes=[
             pltpu.VMEM((H, dh), jnp.float32),
@@ -205,35 +220,37 @@ def paged_flash_decode_gqa(q, pool_k, pool_v, page_table, pos, base, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*scalars, q, pool_k, pool_v)
+    )(*_scalars(page_table, pos, base, layer), q, pool_k, pool_v)
     return o, m[..., 0], l[..., 0]
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "kv_lora", "scale",
                                              "interpret"))
-def paged_flash_decode_mla(q, pool, page_table, pos, base, *,
+def paged_flash_decode_mla(q, pool, page_table, pos, base, layer=0, *,
                            page_size: int, kv_lora: int, scale: float,
                            interpret: bool = False):
-    """q (B,H,R); pool (N, ps, R); → o (B, H, kv_lora), m/l (B, H) f32
-    partials. The pool row is both key (all R dims) and value (first
-    kv_lora dims) — absorbed-MLA decode."""
+    """q (B,H,R); pool (L, N, ps, R), or one layer's (N, ps, R); → o
+    (B, H, kv_lora), m/l (B, H) f32 partials. The pool row is both key
+    (all R dims) and value (first kv_lora dims) — absorbed-MLA decode."""
+    if pool.ndim == 3:
+        pool = pool[None]
     B, H, R = q.shape
-    ps = pool.shape[1]
+    ps = pool.shape[2]
     T = page_table.shape[1]
     grid = (B, T)
-    scalars = (page_table.astype(jnp.int32), pos.astype(jnp.int32),
-               jnp.asarray(base, jnp.int32).reshape(1))
+    row = lambda b, t, pt, p, o, ly: (b, 0, 0)                    # noqa
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, H, R), lambda b, t, pt, p, o: (b, 0, 0)),
-            pl.BlockSpec((1, ps, R), lambda b, t, pt, p, o: (pt[b, t], 0, 0)),
+            pl.BlockSpec((1, H, R), row),
+            pl.BlockSpec((1, 1, ps, R),
+                         lambda b, t, pt, p, o, ly: (ly[0], pt[b, t], 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, H, kv_lora), lambda b, t, pt, p, o: (b, 0, 0)),
-            pl.BlockSpec((1, H, 1), lambda b, t, pt, p, o: (b, 0, 0)),
-            pl.BlockSpec((1, H, 1), lambda b, t, pt, p, o: (b, 0, 0)),
+            pl.BlockSpec((1, H, kv_lora), row),
+            pl.BlockSpec((1, H, 1), row),
+            pl.BlockSpec((1, H, 1), row),
         ],
         scratch_shapes=[
             pltpu.VMEM((H, kv_lora), jnp.float32),
@@ -254,5 +271,5 @@ def paged_flash_decode_mla(q, pool, page_table, pos, base, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*scalars, q, pool)
+    )(*_scalars(page_table, pos, base, layer), q, pool)
     return o, m[..., 0], l[..., 0]

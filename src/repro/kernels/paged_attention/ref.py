@@ -6,7 +6,8 @@ equivalence oracle for the kernel tests, deliberately written in the
 "generic" style the kernel replaces (one `jnp.take` over the page table,
 direct global-max softmax). Numerics: both paths reduce in f32; the
 online-softmax rescaling in the kernel is algebraically identical to the
-single-max form here, so they agree to f32 round-off.
+single-max form here, so they agree to f32 round-off. A stacked pool
+`(L, N, ps, …)` is read at `layer`, a one-layer pool `(N, ps, …)` as is.
 """
 from __future__ import annotations
 
@@ -28,12 +29,15 @@ def _gathered(pool, page_table, base, page_size):
     return g, jnp.broadcast_to(gpos[None], (B, T * ps))
 
 
-def paged_flash_decode_gqa_ref(q, pool_k, pool_v, page_table, pos, base, *,
-                               page_size: int, scale: float,
+def paged_flash_decode_gqa_ref(q, pool_k, pool_v, page_table, pos, base,
+                               layer=0, *, page_size: int, scale: float,
                                softcap: float = 0.0):
-    """Same contract as the kernel: q (B,Hkv,G,dh), pools (N,ps,Hkv,dh) →
-    (o (B,Hkv·G,dh), m (B,Hkv·G), l (B,Hkv·G)) f32 partials."""
+    """Same contract as the kernel: q (B,Hkv,G,dh), pools (L,N,ps,Hkv,dh)
+    or (N,ps,Hkv,dh) → (o (B,Hkv·G,dh), m (B,Hkv·G), l (B,Hkv·G)) f32
+    partials."""
     B, hkv, grp, dh = q.shape
+    if pool_k.ndim == 5:
+        pool_k, pool_v = pool_k[layer], pool_v[layer]
     gk, gpos = _gathered(pool_k, page_table, base, page_size)
     gv, _ = _gathered(pool_v, page_table, base, page_size)
     valid = gpos <= pos[:, None]                           # (B, S)
@@ -51,9 +55,12 @@ def paged_flash_decode_gqa_ref(q, pool_k, pool_v, page_table, pos, base, *,
     return o.reshape(B, H, dh), m.reshape(B, H), l.reshape(B, H)
 
 
-def paged_flash_decode_mla_ref(q, pool, page_table, pos, base, *,
+def paged_flash_decode_mla_ref(q, pool, page_table, pos, base, layer=0, *,
                                page_size: int, kv_lora: int, scale: float):
-    """q (B,H,R); pool (N, ps, R) → (o (B,H,kv_lora), m, l) f32 partials."""
+    """q (B,H,R); pool (L, N, ps, R) or (N, ps, R) → (o (B,H,kv_lora), m,
+    l) f32 partials."""
+    if pool.ndim == 4:
+        pool = pool[layer]
     g, gpos = _gathered(pool, page_table, base, page_size)
     valid = gpos <= pos[:, None]
     s = jnp.einsum("bhr,bsr->bhs", q.astype(F32) * scale, g.astype(F32))

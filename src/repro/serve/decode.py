@@ -21,8 +21,17 @@ Paged decode has two implementations selected by ``paged_kernel``:
   * ``paged_kernel=False`` — the jnp gathered-view implementation
     (`kernels/paged_attention/ref.py`, the PR 2 path) at full table
     width, kept as the escape hatch and the equivalence oracle.
-In both, the in-page write of the new token's K/V stays a separate masked
-scatter (`_paged_write`) *outside* the attention kernel.
+In both, the in-page write of the new token's K/V is a masked B-row
+scatter (`_stacked_write`) just before the attention reads the pool.
+
+Page pools are carried, not scanned: `decode_step`'s layer scan holds each
+pooled leaf as the whole `(L, N, ps, …)` stack in its carry and hands the
+layer index down, so the write lands at `(layer, page, offset)` in place
+and the kernel reads `(layer, pt[b, t])` straight from the stack. As a
+scanned `xs`/`ys` leaf every layer's pool was sliced out of the stack and
+written back each token, and the whole stack copied once per step — pool
+traffic the size of the cache, for one new row per slot. Dense leaves
+(rings, mamba states, non-paged caches) are per-slot and stay scanned.
 """
 from __future__ import annotations
 
@@ -38,6 +47,7 @@ from repro.models import mamba as mamba_mod
 from repro.models import moe as moe_mod
 from repro.models.layers import apply_rope, mlp, rmsnorm, rope_tables, _softcap
 from repro.models.transformer import layer_schedule
+from repro.serve.kv_cache import _is_pooled
 from repro.sharding.axes import ShardCtx
 
 F32 = jnp.float32
@@ -94,6 +104,18 @@ def _paged_write(pool, new_row, pt, pos, i, msize):
     return pool.at[pagec, relc].set(jnp.where(mask, new_row, cur))
 
 
+def _stacked_write(pool, new_row, pt, pos, i, msize, layer):
+    """`_paged_write` into layer `layer` of a stacked pool (L, N, ps_loc, …):
+    the stack is viewed as L·N pages (a free reshape) and the table offset
+    by layer·N, so the write stays a scatter of B rows, which XLA performs
+    in place on a loop carry. A frozen slot's scribble lands in page 0 of
+    layer 0, which is trash in every layer."""
+    L, N = pool.shape[:2]
+    flat = pool.reshape((L * N,) + pool.shape[2:])
+    return _paged_write(flat, new_row, pt + layer * N, pos, i,
+                        msize).reshape(pool.shape)
+
+
 def _paged_impl(paged_kernel) -> str:
     """Map the user-facing ``paged_kernel`` flag onto a
     `kernels/paged_attention/ops.py` impl name: True → backend auto
@@ -132,14 +154,17 @@ def _check_paged_args(page_table, pos, *, update: bool = True,
 def flash_decode_gqa(q, k_new, v_new, ck, cv, pos, *, window: int,
                      scale: float, softcap: float, ctx: ShardCtx,
                      update: bool = True, page_table=None,
-                     paged_kernel=True):
+                     paged_kernel=True, layer=None):
     """q (B,Hkv,G,dh); k_new/v_new (B,Hkv,dh); ck/cv (B,Sc,Hkv,dh) kv_seq-
     sharded; pos (B,). → (out (B,Hkv,G,dh), ck', cv').
 
     update=False → attend-only (whisper cross-attention; pos = valid_len-1).
     page_table (B,T) int32 → paged mode: ck/cv are shared page pools
     (num_pages, page_size, Hkv, dh) with the in-page offset kv_seq-sharded
-    (full attention only — rings stay dense). ``paged_kernel`` selects the
+    (full attention only — rings stay dense); with `layer` (a traced
+    index) they are the stacked pools (L, num_pages, page_size, Hkv, dh)
+    of the decode layer scan, written and read at that layer in place,
+    and returned whole. ``paged_kernel`` selects the
     Pallas in-kernel table walk (True, or an impl string forwarded to
     `kernels/paged_attention/ops.py`) vs. the jnp gathered-view escape
     hatch (False). Either way the per-shard (o, m, l) partials meet the
@@ -155,19 +180,23 @@ def flash_decode_gqa(q, k_new, v_new, ck, cv, pos, *, window: int,
 
     if page_table is not None:
         _check_paged_args(page_table, pos, update=update, window=window)
-        poolspec = ctx.spec((None, "kv_seq", "kv_heads", None), ck.shape)
+        one = layer is None                # one layer's pool: a stack of one
+        if one:
+            ck, cv, layer = ck[None], cv[None], 0
+        poolspec = ctx.spec(("layers", None, "kv_seq", "kv_heads", None),
+                            ck.shape)
         ptspec = P(bp, None)
         # paged_kernel=False → pin the jnp gathered-view oracle (ref.py):
         # full-width table, PR 2 cost model, one shared implementation
         impl = _paged_impl(paged_kernel)
 
-        def local_paged(q, kn, vn, pk, pv, pos, pt):
+        def local_paged(q, kn, vn, pk, pv, pos, pt, ly):
             i = jax.lax.axis_index("model")
-            pk = _paged_write(pk, kn, pt, pos, i, msize)
-            pv = _paged_write(pv, vn, pt, pos, i, msize)
+            pk = _stacked_write(pk, kn, pt, pos, i, msize, ly)
+            pv = _stacked_write(pv, vn, pt, pos, i, msize, ly)
             B, hkv, grp, dh = q.shape
             o, m, l = paged_ops.paged_attend_gqa(
-                q, pk, pv, pt, pos, i, msize, scale=scale,
+                q, pk, pv, pt, pos, i, msize, ly, scale=scale,
                 softcap=softcap, impl=impl)
             o = o.reshape(B, hkv, grp, dh)
             m = m.reshape(B, hkv, grp)
@@ -176,10 +205,12 @@ def flash_decode_gqa(q, k_new, v_new, ck, cv, pos, *, window: int,
 
         fn = jax.shard_map(local_paged, mesh=mesh,
                            in_specs=(qspec, nspec, nspec, poolspec, poolspec,
-                                     pspec, ptspec),
+                                     pspec, ptspec, P()),
                            out_specs=(qspec, poolspec, poolspec),
                            check_vma=False)
-        return fn(q, k_new, v_new, ck, cv, pos, page_table)
+        out, ck, cv = fn(q, k_new, v_new, ck, cv, pos, page_table,
+                         jnp.asarray(layer, jnp.int32))
+        return (out, ck[0], cv[0]) if one else (out, ck, cv)
 
     cspec = ctx.spec(("batch", "kv_seq", "kv_heads", None), ck.shape)
 
@@ -219,11 +250,14 @@ def flash_decode_gqa(q, k_new, v_new, ck, cv, pos, *, window: int,
 
 
 def flash_decode_mla(q_eff, new_row, ckv, pos, *, kv_lora: int, scale: float,
-                     ctx: ShardCtx, page_table=None, paged_kernel=True):
+                     ctx: ShardCtx, page_table=None, paged_kernel=True,
+                     layer=None):
     """q_eff (B,H,R); new_row (B,R); ckv (B,Sc,R). Key = cache row, value =
     first kv_lora dims of the same row. page_table → ckv is the shared pool
-    (num_pages, page_size, R); `paged_kernel` as in flash_decode_gqa (MLA
-    shares the full-attention-only constraint — typed check, not assert)."""
+    (num_pages, page_size, R), or with `layer` the stacked
+    (L, num_pages, page_size, R); `paged_kernel` and `layer` as in
+    flash_decode_gqa (MLA shares the full-attention-only constraint —
+    typed check, not assert)."""
     mesh = ctx.mesh
     bp = ctx.spec(("batch", None, None), q_eff.shape)[0]
     qspec = P(bp, None, None)
@@ -233,22 +267,28 @@ def flash_decode_mla(q_eff, new_row, ckv, pos, *, kv_lora: int, scale: float,
 
     if page_table is not None:
         _check_paged_args(page_table, pos)
-        poolspec = ctx.spec((None, "kv_seq", None), ckv.shape)
+        one = layer is None                # one layer's pool: a stack of one
+        if one:
+            ckv, layer = ckv[None], 0
+        poolspec = ctx.spec(("layers", None, "kv_seq", None), ckv.shape)
         ptspec = P(bp, None)
         impl = _paged_impl(paged_kernel)
 
-        def local_paged(q, row, pool, pos, pt):
+        def local_paged(q, row, pool, pos, pt, ly):
             i = jax.lax.axis_index("model")
-            pool = _paged_write(pool, row, pt, pos, i, msize)
+            pool = _stacked_write(pool, row, pt, pos, i, msize, ly)
             o, m, l = paged_ops.paged_attend_mla(
-                q, pool, pt, pos, i, msize, kv_lora=kv_lora,
+                q, pool, pt, pos, i, msize, ly, kv_lora=kv_lora,
                 scale=scale, impl=impl)
             return _combine(o, m, l).astype(q.dtype), pool
 
         fn = jax.shard_map(local_paged, mesh=mesh,
-                           in_specs=(qspec, nspec, poolspec, pspec, ptspec),
+                           in_specs=(qspec, nspec, poolspec, pspec, ptspec,
+                                     P()),
                            out_specs=(qspec, poolspec), check_vma=False)
-        return fn(q_eff, new_row, ckv, pos, page_table)
+        out, ckv = fn(q_eff, new_row, ckv, pos, page_table,
+                      jnp.asarray(layer, jnp.int32))
+        return (out, ckv[0]) if one else (out, ckv)
 
     cspec = ctx.spec(("batch", "kv_seq", None), ckv.shape)
 
@@ -280,8 +320,8 @@ def flash_decode_mla(q_eff, new_row, ckv, pos, *, kv_lora: int, scale: float,
 
 # --------------------------------------------------------- per-block decode
 def gqa_decode(cfg: ModelConfig, p, x, cache, pos, window, ctx: ShardCtx,
-               page_table=None, paged_kernel=True):
-    """x (B,D) → (out (B,D), new cache)."""
+               page_table=None, paged_kernel=True, layer=None):
+    """x (B,D) → (out (B,D), new cache); `layer` as in flash_decode_gqa."""
     B = x.shape[0]
     q = jnp.einsum("bd,dhk->bhk", x, p["wq"])
     k = jnp.einsum("bd,dhk->bhk", x, p["wk"])
@@ -295,7 +335,7 @@ def gqa_decode(cfg: ModelConfig, p, x, cache, pos, window, ctx: ShardCtx,
     out, ck, cv = flash_decode_gqa(
         qg, k, v, cache["k"], cache["v"], pos, window=window,
         scale=cfg.head_dim ** -0.5, softcap=cfg.attn_softcap, ctx=ctx,
-        page_table=page_table, paged_kernel=paged_kernel)
+        page_table=page_table, paged_kernel=paged_kernel, layer=layer)
     out = out.reshape(B, cfg.n_heads * cfg.head_dim)
     o = jnp.einsum("bk,kd->bd",
                    out, p["wo"].reshape(-1, cfg.d_model))
@@ -303,7 +343,7 @@ def gqa_decode(cfg: ModelConfig, p, x, cache, pos, window, ctx: ShardCtx,
 
 
 def mla_decode(cfg: ModelConfig, p, x, cache, pos, ctx: ShardCtx,
-               page_table=None, paged_kernel=True):
+               page_table=None, paged_kernel=True, layer=None):
     m = cfg.mla
     B = x.shape[0]
     x3 = x[:, None, :]
@@ -328,7 +368,7 @@ def mla_decode(cfg: ModelConfig, p, x, cache, pos, ctx: ShardCtx,
     o_c, ckv = flash_decode_mla(q_eff, row, cache["ckv"], pos,
                                 kv_lora=m.kv_lora, scale=scale, ctx=ctx,
                                 page_table=page_table,
-                                paged_kernel=paged_kernel)
+                                paged_kernel=paged_kernel, layer=layer)
     # un-absorb values: o = (o_c · W_uv) then output proj
     wuv = p["wukv"][..., m.nope_dim:]                  # (R, H, v)
     o = jnp.einsum("bhr,rhv->bhv", o_c, wuv)
@@ -337,7 +377,9 @@ def mla_decode(cfg: ModelConfig, p, x, cache, pos, ctx: ShardCtx,
 
 
 def block_decode(cfg: ModelConfig, bc, p, cache, h, pos, ctx: ShardCtx,
-                 page_table=None, paged_kernel=True):
+                 page_table=None, paged_kernel=True, layer=None):
+    """`layer` set → `cache` holds this slot's stacked page pools, read and
+    written at that index (see `decode_step`)."""
     x = rmsnorm(h, p["norm1"], cfg.norm_eps)
     if bc.mixer == "attn":
         # only full-attention layers are paged; rings keep dense buffers
@@ -345,11 +387,11 @@ def block_decode(cfg: ModelConfig, bc, p, cache, h, pos, ctx: ShardCtx,
         if cfg.mla:
             y, new_cache = mla_decode(cfg, p["attn"], x, cache, pos, ctx,
                                       page_table=pt,
-                                      paged_kernel=paged_kernel)
+                                      paged_kernel=paged_kernel, layer=layer)
         else:
             y, new_cache = gqa_decode(cfg, p["attn"], x, cache, pos,
                                       bc.window, ctx, page_table=pt,
-                                      paged_kernel=paged_kernel)
+                                      paged_kernel=paged_kernel, layer=layer)
     else:
         step = (mamba_mod.mamba2_step if cfg.ssm.version == 2
                 else mamba_mod.mamba1_step)
@@ -373,7 +415,11 @@ def block_decode(cfg: ModelConfig, bc, p, cache, h, pos, ctx: ShardCtx,
 def decode_step(cfg: ModelConfig, params, cache, tokens, pos, ctx: ShardCtx,
                 page_table=None, paged_kernel=True):
     """tokens (B,), pos (B,) → (logits (B,V) f32 vocab-sharded, new cache).
-    page_table (B,T) → full-attention cache leaves are page pools."""
+    page_table (B,T) → full-attention cache leaves are page pools.
+
+    Pooled slots ride in the layer scan's carry as whole `(L, N, ps, …)`
+    stacks, addressed by the scanned layer index; every other slot is
+    scanned per layer as `xs`/`ys` (module docstring)."""
     segments = layer_schedule(cfg)
     h = jnp.take(params["embed"]["table"], tokens, axis=0).astype(cfg.pdtype)
     if cfg.embed_scale:
@@ -381,20 +427,32 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, pos, ctx: ShardCtx,
     h = ctx.constrain(h, ("batch", None))
     new_blocks = []
     for seg, sp, sc in zip(segments, params["blocks"], cache["blocks"]):
+        pooled = {f"s{j}" for j, bc in enumerate(seg.pattern)
+                  if page_table is not None and _is_pooled(bc)}
+        pools = {k: v for k, v in sc.items() if k in pooled}
+        rest = {k: v for k, v in sc.items() if k not in pooled}
 
-        def body(hc, xs, seg=seg):
-            slot_params, slot_cache = xs
-            new_slot = {}
+        def body(carry, xs, seg=seg, pooled=pooled):
+            hc, pools = carry
+            layer, slot_params, slot_cache = xs
+            pools, new_slot = dict(pools), {}
             for j, bc in enumerate(seg.pattern):
-                hc, nc = block_decode(cfg, bc, slot_params[f"s{j}"],
-                                      slot_cache[f"s{j}"], hc, pos, ctx,
-                                      page_table=page_table,
-                                      paged_kernel=paged_kernel)
-                new_slot[f"s{j}"] = nc
-            return hc, new_slot
+                key = f"s{j}"
+                if key in pooled:
+                    hc, pools[key] = block_decode(
+                        cfg, bc, slot_params[key], pools[key], hc, pos, ctx,
+                        page_table=page_table, paged_kernel=paged_kernel,
+                        layer=layer)
+                else:
+                    hc, new_slot[key] = block_decode(
+                        cfg, bc, slot_params[key], slot_cache[key], hc, pos,
+                        ctx, page_table=page_table,
+                        paged_kernel=paged_kernel)
+            return (hc, pools), new_slot
 
-        h, new_sc = jax.lax.scan(body, h, (sp, sc))
-        new_blocks.append(new_sc)
+        (h, pools), new_sc = jax.lax.scan(
+            body, (h, pools), (jnp.arange(seg.repeat), sp, rest))
+        new_blocks.append({**new_sc, **pools})
     h = rmsnorm(h, params["final_norm"], cfg.norm_eps)
     w = (params["embed"]["table"].T if cfg.tie_embeddings
          else params["unembed"]["w"])

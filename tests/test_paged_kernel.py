@@ -85,6 +85,89 @@ def test_kernel_matches_ref_mla(page_size):
                                    rtol=2e-5, atol=2e-5)
 
 
+# ------------------------------- stacked pools, as the decode scan carries
+L_STACK = 3
+
+
+def _stack(pool, seed):
+    """(N, ps, …) → (L_STACK, N, ps, …): random layers around `pool`."""
+    rng = np.random.default_rng(seed)
+    return jnp.stack([jnp.asarray(rng.normal(size=pool.shape), pool.dtype)
+                      if l != 1 else pool for l in range(L_STACK)])
+
+
+def _mla_case(seed=1, B=3, H=4, R=24, T=4, ps=8):
+    rng = np.random.default_rng(seed)
+    N = 1 + B * T
+    q = jnp.asarray(rng.normal(size=(B, H, R)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(N, ps, R)), jnp.float32)
+    pt = jnp.asarray(1 + rng.permutation(N - 1)[:B * T].reshape(B, T),
+                     jnp.int32)
+    pos = jnp.asarray([0, ps + 2, T * ps - 1], jnp.int32)
+    return q, pool, pt, pos
+
+
+@pytest.mark.parametrize("impl", [KERNEL, "ref"])
+@pytest.mark.parametrize("layer", [0, L_STACK - 1])
+@pytest.mark.parametrize("kind", ["gqa", "mla"])
+def test_stacked_pool_at_layer_matches_one_layer(kind, layer, impl):
+    """The kernel (and the ref oracle) on the stacked pool at layer l gives
+    exactly what it gives on pool[l] alone."""
+    if kind == "gqa":
+        q, pk, pv, pt, pos = _gqa_case(8, seed=11)
+        sk, sv = _stack(pk, 12), _stack(pv, 13)
+        got = ops.paged_attend_gqa(q, sk, sv, pt, pos, 0, 1, layer,
+                                   scale=0.25, impl=impl)
+        want = ops.paged_attend_gqa(q, sk[layer], sv[layer], pt, pos, 0, 1,
+                                    scale=0.25, impl=impl)
+    else:
+        q, pool, pt, pos = _mla_case()
+        sp = _stack(pool, 14)
+        got = ops.paged_attend_mla(q, sp, pt, pos, 0, 1, layer, kv_lora=16,
+                                   scale=0.2, impl=impl)
+        want = ops.paged_attend_mla(q, sp[layer], pt, pos, 0, 1, kv_lora=16,
+                                    scale=0.2, impl=impl)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("flag", [KERNEL, False])
+@pytest.mark.parametrize("kind", ["gqa", "mla"])
+def test_decode_writes_only_its_layer(kind, flag, ctx):
+    """A decode step on the stacked pool at layer 1 writes one row per slot
+    into layer 1 only, as the one-layer call does: layers 0 and 2 and the
+    trash page 0 keep every byte, and its output is the one-layer call's."""
+    rng = np.random.default_rng(15)
+    if kind == "gqa":
+        q, pk, pv, pt, pos = _gqa_case(8, seed=16)
+        kn = jnp.asarray(rng.normal(size=(3, 2, 16)), jnp.float32)
+        vn = jnp.asarray(rng.normal(size=(3, 2, 16)), jnp.float32)
+        pools = (_stack(pk, 17), _stack(pv, 18))
+        kw = dict(window=0, scale=0.25, softcap=0.0, ctx=ctx, page_table=pt,
+                  paged_kernel=flag)
+        o, *new = flash_decode_gqa(q, kn, vn, *pools, pos, layer=1, **kw)
+        o1, *new1 = flash_decode_gqa(q, kn, vn, *(p[1] for p in pools), pos,
+                                     **kw)
+    else:
+        q, pool, pt, pos = _mla_case(seed=19)
+        row = jnp.asarray(rng.normal(size=(3, 24)), jnp.float32)
+        pools = (_stack(pool, 20),)
+        kw = dict(kv_lora=16, scale=0.2, ctx=ctx, page_table=pt,
+                  paged_kernel=flag)
+        o, *new = flash_decode_mla(q, row, *pools, pos, layer=1, **kw)
+        o1, *new1 = flash_decode_mla(q, row, pools[0][1], pos, **kw)
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(o1))
+    for old, got, one in zip(pools, new, new1):
+        old, got = np.asarray(old), np.asarray(got)
+        assert got.shape == old.shape
+        np.testing.assert_array_equal(got[1], np.asarray(one))
+        for l in (0, 2):
+            np.testing.assert_array_equal(got[l], old[l])
+        np.testing.assert_array_equal(got[:, 0], old[:, 0])   # trash page
+        changed = np.any(got[1] != old[1], axis=tuple(range(2, old.ndim - 1)))
+        assert changed.sum() == pos.shape[0]     # one (page, offset) per slot
+
+
 # ------------------------------------------- full decode path, both flags
 def test_flash_decode_gqa_kernel_vs_gather(ctx):
     """kernel and jnp-gather paths agree through the full flash_decode_gqa

@@ -5,21 +5,32 @@ Interpret mode runs the kernel body on the CPU but never applies the
 chip's tiling and memory rules; the TPU compiler here does. Each case
 lowers the kernel exactly as the serving engine calls it (bf16 query and
 pool, 8 slots, page size 16, a 128-page live table) and asserts that the
-compiled program holds the Mosaic kernel (``tpu_custom_call``).
+compiled program holds the Mosaic kernel (``tpu_custom_call``). One
+case compiles a whole paged decode quantum and checks that the stacked
+page pool stays in place through it.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
 file.
 """
 import os
+import re
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import all_configs
+from repro.kernels.paged_attention import ops as paged_ops
 from repro.kernels.paged_attention.paged_attention import (
     paged_flash_decode_gqa, paged_flash_decode_mla)
+from repro.models.model import model_defs
+from repro.serve.decode import decode_loop_fn
+from repro.serve.kv_cache import paged_cache_defs
+from repro.sharding import params as prm
+from repro.sharding.axes import single_device_ctx
 
 B, PAGE, T = 8, 16, 128            # slots, page size, live table width
 N_PAGES = 1 + B * T                # pool incl. the reserved trash page 0
@@ -86,3 +97,41 @@ def test_paged_mla_kernel_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
     o, m, l = compiled.out_info
     assert o.shape == (B, H, kv_lora) and m.shape == l.shape == (B, H)
+
+
+def test_paged_decode_quantum_keeps_pool_in_place(topo, one_chip,
+                                                  monkeypatch):
+    """A paged decode quantum through ``decode_loop_fn`` with the Pallas
+    kernel, at NeMo's KV widths (8 kv heads of 128, page 16), 2 layers,
+    8 slots, the cache donated: the stacked pool rides the layer scan's
+    carry, so the optimised program holds no copy, dynamic-slice or
+    dynamic-update-slice of one layer's pool or of the stack, and needs
+    less scratch than one layer's pool. The pool (17 MB a layer) is too
+    large for the compiler to stage in on-chip memory."""
+    L, n_pages = 2, 1 + B * 64
+    cfg = replace(all_configs()["mistral-nemo-12b"], n_layers=L,
+                  d_model=256, n_heads=32, n_kv_heads=8, head_dim=128,
+                  d_ff=512, vocab=512)
+    ctx = single_device_ctx(topo.devices[0])
+    monkeypatch.setattr(paged_ops, "_resolve", lambda impl: ("kernel", False))
+    params = prm.abstract(model_defs(cfg), ctx)
+    cache = prm.abstract(paged_cache_defs(cfg, B, T * PAGE, 1,
+                                          num_pages=n_pages,
+                                          page_size=PAGE), ctx)
+    fn = decode_loop_fn(cfg, ctx, num_steps=4, eos_id=-1, max_len=T * PAGE,
+                        paged=True)
+    i32 = lambda *s: _spec(s, jnp.int32, one_chip)                # noqa
+    compiled = jax.jit(fn, donate_argnums=(1, 2, 3, 4, 5, 6)).lower(
+        params, cache, i32(B), i32(B), _spec((B,), jnp.bool_, one_chip),
+        i32(B), _spec((2,), jnp.uint32, one_chip), i32(B, T)).compile()
+    hlo = compiled.as_text()
+    # the kernel keeps the op name the benchmark's trace reader matches
+    assert re.search(r"%paged_flash_decode_gqa[.\w]* = .* custom-call\(",
+                     hlo)
+    moved = re.findall(
+        rf"= bf16\[(?:\d+,)?{n_pages},{PAGE},8,128\]\S* "
+        r"(copy|copy-start|dynamic-slice|dynamic-update-slice)\(", hlo)
+    assert not moved, f"pool-sized {sorted(set(moved))} in the quantum"
+    layer_pool = n_pages * PAGE * 8 * 128 * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < layer_pool, (temp, layer_pool)
