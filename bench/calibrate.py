@@ -32,10 +32,8 @@ def main(argv=None) -> None:
     sys.path[:0] = [str(CHECKOUT), str(CHECKOUT / "src")]
     import jax
     from bench import harness, manifest
-    from bench.serving import model_shape
     harness.use_checkout_cache()
     cell = manifest.load_cell(args.workload)
-    shape = model_shape(cell.config)
     clock = harness.CompileClock()
     devices = jax.devices()[:cell.chips]
     for seed in args.seeds:
@@ -44,7 +42,8 @@ def main(argv=None) -> None:
                                             False, clock)
         del server
         gc.collect()
-        gaps = harness.check(shape, seed, reqs, controls=args.controls)
+        gaps = harness.check(cell.config, seed, reqs,
+                             controls=args.controls)
         print(json.dumps({"seed": seed, "failed": facts["failed"],
                           "sent": facts["sent"],
                           "drain_s": facts["drain_s"], **(gaps or {})}),

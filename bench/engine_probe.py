@@ -227,7 +227,7 @@ def probe(cell, seed: int, seconds: float, trace: bool, peaks: dict,
     from bench import manifest
     from bench.harness import (TRACE_DIR, CompileClock, measure, prepare,
                                record)
-    from bench.serving import engines, model_shape
+    from bench.serving import engines
     from bench.trace import reduce_events
     clock = CompileClock()
     server, arrivals = prepare(cell, seed, seconds, devices)
@@ -235,8 +235,7 @@ def probe(cell, seed: int, seconds: float, trace: bool, peaks: dict,
     watch = Watch(server, engs)
     reqs, stats, steps, facts = measure(watch, arrivals, seconds, trace,
                                         clock)
-    rec = record(cell, model_shape(cell.config), engs[0].decode_quantum,
-                 seconds, peaks, stats, steps, facts)
+    rec = record(cell, server, seconds, peaks, stats, steps, facts)
     out = readings(watch, reqs, stats, facts)
     out["end_to_end"] = {m["name"]: manifest.reader(m["name"])(rec)
                          for m in cell.end_to_end if m["name"] != "setup_s"}

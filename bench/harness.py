@@ -16,6 +16,7 @@ import os
 import shutil
 import sys
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
 import jax
@@ -23,7 +24,7 @@ import numpy as np
 
 from bench import manifest
 from bench.reference import logit_gaps
-from bench.serving import build_server, engines, model_shape
+from bench.serving import build_server, engines
 
 DRAIN_LIMIT_S = 150.0        # past this after the window, a request failed
 SAMPLE_TOKENS = 512          # served tokens the check compares, at least
@@ -91,7 +92,7 @@ class StepStat:
     decode_keys: int = 0      # sum over decode tokens of keys attended
     prefill_tokens: int = 0
     prefill_keys: int = 0     # sum over prompt tokens of keys attended
-    per_tier: dict = field(default_factory=dict)
+    per_tier: dict = field(default_factory=dict)   # tier -> tokens decoded
 
 
 @dataclass
@@ -104,6 +105,7 @@ class Record:
     decode_quantum: int
     window_s: float
     peaks: dict
+    tiers: tuple = ()         # the router's tiers; none for one engine
     setup_s: float = 0.0
     setup_compile_s: float = 0.0
     requests: list = field(default_factory=list)
@@ -237,17 +239,22 @@ def warm_requests(eng, prompt_lens, max_total: int) -> list[int]:
 
 
 def warm_up(engs, lens, vocab: int) -> None:
-    """Serve one request of each length alone on every engine: a prefill
-    and one decode quantum each."""
+    """Serve one request of each length alone on every engine, the
+    engines side by side, one thread each: a prefill and one decode
+    quantum each."""
     from repro.serve.engine import Request
-    rng = np.random.default_rng(0)
-    for eng in engs:
+
+    def warm(eng):
+        rng = np.random.default_rng(0)
         for k, n in enumerate(lens):
             eng.submit(Request(rid=-1 - k,
                                prompt=rng.integers(0, vocab, n).tolist(),
                                max_new=eng.quantum_tokens + 1))
             while eng.has_work():
                 eng.step()
+    with ThreadPoolExecutor(len(engs)) as pool:
+        for f in [pool.submit(warm, eng) for eng in engs]:
+            f.result()
 
 
 def sample(reqs, seed: int) -> list[int]:
@@ -277,10 +284,12 @@ def peak_bytes(devices) -> int:
 
 def prepare(cell: manifest.Cell, seed: int, seconds: float, devices,
             mix: dict | None = None):
-    """Build the server over the seed's weights, make the window's
-    requests, and warm every program they will use."""
-    shape = model_shape(cell.config)
-    server = build_server(cell.config, seed, devices)
+    """Build the server over the seed's weights, one replica on each of
+    ``devices``, make the window's requests, and warm every program they
+    will use."""
+    model = manifest.model(cell.config)
+    shape = model.model_shape(cell.config)
+    server = build_server(model, cell.config, seed, devices)
     mix = mix or cell.traffic
     gen = manifest.generator(mix["kind"])
     arrivals = gen.generate(mix, seconds, seed, shape["vocab_size"])
@@ -310,23 +319,28 @@ def measure(server, arrivals, seconds: float, traced: bool,
     return reqs, stats, steps, facts
 
 
-def record(cell, shape, eng_quantum, seconds, peaks, stats, steps,
-           facts) -> Record:
+def record(cell, server, seconds, peaks, stats, steps, facts) -> Record:
+    tiers = getattr(server, "tiers", ())
     return Record(cell=cell.name, chips=cell.chips, config=cell.config,
-                  shape=shape, decode_quantum=eng_quantum,
-                  window_s=float(seconds), peaks=peaks, requests=stats,
+                  shape=manifest.model(cell.config).model_shape(cell.config),
+                  decode_quantum=engines(server)[0].decode_quantum,
+                  window_s=float(seconds), peaks=peaks,
+                  tiers=tuple(t.name for t in tiers), requests=stats,
                   steps=steps, loop_end=facts["loop_end"],
                   drain_end=facts["drain_end"])
 
 
-def check(shape: dict, seed: int, reqs, controls=()) -> dict | None:
-    """The reference over a sample of the finished requests."""
+def check(config: dict, seed: int, reqs, controls=()) -> dict | None:
+    """The reference of the configuration's model over a sample of the
+    finished requests."""
     picks = sample(reqs, seed)
     if not picks:
         return None
+    model = manifest.model(config)
     t0 = time.perf_counter()
-    gaps = logit_gaps(shape, seed, [(reqs[j].prompt, list(reqs[j].out))
-                                    for j in picks], controls=controls)
+    gaps = logit_gaps(model, model.model_shape(config), seed,
+                      [(reqs[j].prompt, list(reqs[j].out)) for j in picks],
+                      controls=controls)
     gaps["requests"] = len(picks)
     gaps["seconds"] = time.perf_counter() - t0
     return gaps
@@ -336,16 +350,14 @@ def run_cell(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
              t_start: float, peaks: dict, devices) -> dict:
     """A whole run after the device check. Returns the result object."""
     clock = CompileClock()
-    shape = model_shape(cell.config)
     server, arrivals = prepare(cell, seed, seconds, devices)
-    quantum = engines(server)[0].decode_quantum
     setup_compile_s, hits = clock.secs, clock.hits
     setup_s = time.perf_counter() - t_start
     reqs, stats, steps, facts = measure(server, arrivals, seconds, trace,
                                         clock)
-    rec = record(cell, shape, quantum, seconds, peaks, stats, steps, facts)
+    rec = record(cell, server, seconds, peaks, stats, steps, facts)
     rec.setup_s, rec.setup_compile_s = setup_s, setup_compile_s
-    mem = peak_bytes(devices[:cell.chips])
+    mem = peak_bytes(devices)
     print(f"[bench] setup {setup_s:.3f} s (compile {setup_compile_s:.3f} "
           f"s, cache hits {hits}); compiles in window: "
           f"{facts['compiles_in_window']}; sent {facts['sent']}; generator "
@@ -358,7 +370,7 @@ def run_cell(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
         from bench.trace import summarize
         rec.trace = summarize(TRACE_DIR)
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
-    gaps = check(shape, seed, reqs)
+    gaps = check(cell.config, seed, reqs)
     gap = gaps["program"] if gaps else math.inf
     if gaps:
         print(f"[bench] reference over {gaps['requests']} requests, "

@@ -2,10 +2,12 @@
 
 Everything a cell needs is data beside this file: ``BENCHMARK.json`` at
 the checkout's root names the cells and metrics, ``configs/<name>.json``
-holds a configuration, ``traffic/<name>.json`` a traffic mix (which may
+holds a configuration (which may name its model module,
+``models/<name>.py``), ``traffic/<name>.json`` a traffic mix (which may
 name a ``base`` mix whose keys it overrides, and names the ``kind`` of
 generator, ``traffic/<kind>.py``), and ``metrics/<name>.py`` the reader of
-one metric. A new cell, mix or metric is new files and entries.
+one metric. A new cell, configuration, model, mix or metric is new files
+and entries.
 """
 from __future__ import annotations
 
@@ -28,8 +30,9 @@ def _module(path: Path, name: str):
     if not path.is_file():
         raise FileNotFoundError(f"no file {path} for {name!r}")
     key = f"bench._loaded.{name}"
-    if key in sys.modules:
-        return sys.modules[key]
+    mod = sys.modules.get(key)
+    if mod is not None and mod.__file__ == str(path):
+        return mod
     spec = importlib.util.spec_from_file_location(key, path)
     mod = importlib.util.module_from_spec(spec)
     sys.modules[key] = mod
@@ -61,6 +64,13 @@ def load_mix(name: str, root: Path = HERE) -> dict:
 def generator(kind: str, root: Path = HERE):
     """The traffic generator module a mix's ``kind`` names."""
     return _module(root / "traffic" / f"{kind}.py", f"traffic_{kind}")
+
+
+def model(config: dict, root: Path = HERE):
+    """The model module a configuration names (``"model"``; ``plain``
+    where it names none): see ``models/__init__.py``."""
+    name = config.get("model", "plain")
+    return _module(root / "models" / f"{name}.py", f"model_{name}")
 
 
 def reader(metric: str, root: Path = HERE):

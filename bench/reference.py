@@ -1,15 +1,14 @@
 """Plain float32 reference of the served models, and the comparison that
 decides ``correct``.
 
-The forward pass follows the published Llama/Mistral block: RMSNorm,
-grouped-query attention with rotary embeddings (half rotation, as the
-Hugging Face code applies them), causal masking with an optional sliding
-window, a SwiGLU feed-forward, a final RMSNorm and an untied output
-projection. Shapes come from the configuration file's published keys.
-Everything is float32 at ``HIGHEST`` matmul precision, one layer at a
-time, one request at a time, and the weights are drawn again from the
-seed (``bench.weights``): nothing here imports or receives anything the
-program made.
+The forward pass is the configuration's model module's
+(``bench/models``: for ``plain``, the published Llama/Mistral block)
+between what every model here shares: the embedding, the final norm's
+gain and an untied output projection, drawn in vocabulary blocks. Shapes
+come from the configuration file's published keys. Everything is float32
+at ``HIGHEST`` matmul precision, one layer at a time, one request at a
+time, and the weights are drawn again from the seed (``bench.weights``):
+nothing here imports or receives anything the program made.
 
 What is compared (``logit_gaps``): the reference runs over each sampled
 request's prompt followed by the tokens the program served, and at each
@@ -34,17 +33,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.weights import (CONTRACTED, LAYER_LEAVES, draw, root_key,
-                           vocab_blocks)
+from bench.weights import CONTRACTED, root_key, shared_draw, vocab_blocks
 
 HI = jax.lax.Precision.HIGHEST
 Q_CHUNK = 512          # query rows per attention block
 QMAX = {"fp8": 448.0, "int8": 127.0}
 
 
-def _round(x: jax.Array, axes, kind: str | None) -> jax.Array:
-    """Round x to ``kind`` with one absmax scale per slice over ``axes``,
-    and return it dequantized to float32."""
+def quantize(x: jax.Array, axes, kind: str | None) -> jax.Array:
+    """Round x to ``kind`` with one absmax scale per slice over ``axes``
+    (a weight's input side: one scale per output channel), and return it
+    dequantized to float32. ``kind`` None leaves x as it is."""
     if kind is None:
         return x
     amax = jnp.max(jnp.abs(x), axis=axes, keepdims=True)
@@ -57,84 +56,17 @@ def _round(x: jax.Array, axes, kind: str | None) -> jax.Array:
     return y * scale
 
 
-def quantize(w: jax.Array, name: str, kind: str | None) -> jax.Array:
-    """A float32 weight rounded to ``kind``, one scale per output channel."""
-    if name not in CONTRACTED:
-        return w
-    return _round(w, CONTRACTED[name], kind)
-
-
-def _act(x: jax.Array, kind: str | None) -> jax.Array:
+def act(x: jax.Array, kind: str | None) -> jax.Array:
     """An activation rounded to ``kind``, one scale per token (and head)."""
-    return _round(x, -1, kind)
-
-
-def _rms(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-
-def _rope(x, pos, theta):
-    """x (S, heads, dh); rotate the two halves of each head."""
-    half = x.shape[-1] // 2
-    freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    ang = pos.astype(jnp.float32)[:, None] * freqs          # (S, half)
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-@partial(jax.jit,
-         static_argnames=("H", "K", "theta", "eps", "window", "kind"))
-def _layer(h, w, *, H, K, theta, eps, window, kind=None):
-    """One block over one padded sequence h (S, D) float32. With ``kind``
-    (a control) every matmul input and the keys and values are rounded to
-    that precision as well as the weights."""
-    S = h.shape[0]
-    pos = jnp.arange(S)
-    x = _act(_rms(h, w["attn_norm"], eps), kind)
-    q = _rope(jnp.einsum("sd,dhk->shk", x, w["wq"], precision=HI), pos,
-              theta)
-    k = _act(_rope(jnp.einsum("sd,dhk->shk", x, w["wk"], precision=HI),
-                   pos, theta), kind)
-    v = _act(jnp.einsum("sd,dhk->shk", x, w["wv"], precision=HI), kind)
-    dh = q.shape[-1]
-    G = H // K
-    k = jnp.repeat(k, G, axis=1)            # head i reads kv head i // G
-    v = jnp.repeat(v, G, axis=1)
-
-    def block(q0):
-        qb = jax.lax.dynamic_slice_in_dim(q, q0, Q_CHUNK, 0)
-        s = jnp.einsum("qhd,khd->hqk", qb, k, precision=HI) * dh ** -0.5
-        i = q0 + jnp.arange(Q_CHUNK)[:, None]
-        j = jnp.arange(S)[None, :]
-        ok = j <= i
-        if window:
-            ok &= j > i - window
-        s = jnp.where(ok[None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("hqk,khd->qhd", p, v, precision=HI)
-
-    o = jax.lax.map(block, jnp.arange(0, S, Q_CHUNK))
-    o = _act(o.reshape(S, H * dh), kind).reshape(S, H, dh)
-    h = h + jnp.einsum("shk,hkd->sd", o, w["wo"], precision=HI)
-    x = _act(_rms(h, w["mlp_norm"], eps), kind)
-    g = jnp.einsum("sd,df->sf", x, w["w_gate"], precision=HI)
-    u = jnp.einsum("sd,df->sf", x, w["w_up"], precision=HI)
-    return h + jnp.einsum("sf,fd->sd", _act(jax.nn.silu(g) * u, kind),
-                          w["w_down"], precision=HI)
-
-
-@partial(jax.jit, static_argnames=("shape_items", "kind"))
-def _layer_weights(root, layer, *, shape_items, kind):
-    shape = dict(shape_items)
-    return {n: quantize(draw(root, shape, n, layer), n, kind)
-            for n in LAYER_LEAVES}
+    return quantize(x, -1, kind)
 
 
 @partial(jax.jit, static_argnames=("shape_items", "name", "rows", "kind"))
 def _leaf(root, index, *, shape_items, name, rows, kind):
-    shape = dict(shape_items)
-    return quantize(draw(root, shape, name, index, rows), name, kind)
+    """A shared leaf's draw, rounded to ``kind`` where it is a matmul
+    weight."""
+    w = shared_draw(root, dict(shape_items), name, index, rows)
+    return quantize(w, CONTRACTED[name], kind) if name in CONTRACTED else w
 
 
 @jax.jit
@@ -146,9 +78,10 @@ def _embed_block(h, table, toks, v0):
     return h + jnp.where(ok[:, None], rows, 0.0)
 
 
-@partial(jax.jit, static_argnames=("eps",))
-def _final_rows(h, rows, gain, *, eps):
-    return _rms(jnp.take(h, rows, axis=0), gain, eps)
+@partial(jax.jit, static_argnames=("model", "shape_items"))
+def _final_rows(h, rows, gain, *, model, shape_items):
+    return model.final_norm(jnp.take(h, rows, axis=0), gain,
+                            dict(shape_items))
 
 
 @jax.jit
@@ -184,8 +117,10 @@ def _bucket(n: int) -> int:
     return b
 
 
-def logit_gaps(shape: dict, seed: int, samples, *, controls=()) -> dict:
-    """Widest logit gap of the served tokens, and of each control.
+def logit_gaps(model, shape: dict, seed: int, samples, *,
+               controls=()) -> dict:
+    """Widest logit gap of the served tokens, and of each control, with
+    the block of ``model`` (a module of ``bench/models``) over ``shape``.
 
     samples: ``(prompt, served)`` token lists. Returns ``{"program":
     widest gap, "controls": {kind: widest gap}, "positions": count}``,
@@ -194,10 +129,6 @@ def logit_gaps(shape: dict, seed: int, samples, *, controls=()) -> dict:
     """
     root = root_key(seed)
     items = tuple(sorted(shape.items()))
-    H, K = shape["num_attention_heads"], shape["num_key_value_heads"]
-    kw = dict(H=H, K=K, theta=float(shape["rope_theta"]),
-              eps=float(shape["rms_norm_eps"]),
-              window=int(shape.get("sliding_window") or 0))
     kinds = (None,) + tuple(controls)
     seqs, rows, served = [], [], []
     for prompt, out in samples:
@@ -219,13 +150,13 @@ def logit_gaps(shape: dict, seed: int, samples, *, controls=()) -> dict:
             del table
     for layer in range(shape["num_hidden_layers"]):
         for kind in kinds:
-            w = _layer_weights(root, layer, shape_items=items, kind=kind)
-            hs[kind] = [_layer(h, w, kind=kind, **kw) for h in hs[kind]]
+            w = model.layer_weights(root, shape, layer, kind)
+            hs[kind] = [model.layer(h, w, shape, kind) for h in hs[kind]]
             del w
     gain = _leaf(root, 0, shape_items=items, name="final_norm", rows=0,
                  kind=None)
-    fin = {kind: _act(jnp.concatenate(
-        [_final_rows(h, jnp.asarray(r), gain, eps=kw["eps"])
+    fin = {kind: act(jnp.concatenate(
+        [_final_rows(h, jnp.asarray(r), gain, model=model, shape_items=items)
          for h, r in zip(hs[kind], rows)]), kind) for kind in kinds}
     del hs
     served_all = jnp.asarray(np.concatenate(served))

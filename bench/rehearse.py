@@ -53,8 +53,7 @@ def main(argv=None) -> None:
 
     from bench import manifest
     from bench.harness import warm_requests
-    from bench.serving import (ENGINE_KEYS, model_shape, params_fn,
-                               program_config)
+    from bench.serving import engine_kwargs
     from bench.weights import root_key
     from repro.kernels.paged_attention import ops as paged_ops
     from repro.serve import engine as engine_mod
@@ -68,8 +67,9 @@ def main(argv=None) -> None:
     for key in ("max_slots", "num_pages", "prefill_batch"):
         if getattr(args, key) is not None:
             conf["engine"][key] = getattr(args, key)
-    cfg = program_config(conf)
-    shape = model_shape(conf)
+    model = manifest.model(conf)
+    cfg = model.program_config(conf)
+    shape = model.model_shape(conf)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     dev = topo.devices[0]
@@ -85,8 +85,8 @@ def main(argv=None) -> None:
     jax.device_put = lambda x, s=None: jax.tree.map(spec, x)
     prm.materialize_sharded = lambda defs, key, c: prm.abstract(defs, c)
     paged_ops._resolve = lambda impl: ("kernel", False)
-    kw = {k: conf["engine"][k] for k in ENGINE_KEYS if k in conf["engine"]}
-    build, defs = params_fn(cfg, shape)
+    kw = engine_kwargs(conf)
+    build, defs = model.params_fn(cfg, shape)
     params = prm.abstract(defs, ctx)
     eng = engine_mod.Engine(cfg, params, ctx, **kw)
     jax.device_put = real_put

@@ -33,11 +33,12 @@ def main(argv=None) -> None:
     sys.path[:0] = [str(CHECKOUT), str(CHECKOUT / "src")]
     import jax
     from bench import harness, manifest
-    from bench.serving import engines, model_shape
+    from bench.serving import engines
     from bench.stats import percentile
     harness.use_checkout_cache()
     cell = manifest.load_cell(args.workload)
-    shape = model_shape(cell.config)
+    vocab = manifest.model(cell.config).model_shape(cell.config)[
+        "vocab_size"]
     clock = harness.CompileClock()
     devices = jax.devices()[:cell.chips]
     gen = manifest.generator(cell.traffic["kind"])
@@ -48,21 +49,19 @@ def main(argv=None) -> None:
             server, arrivals = harness.prepare(cell, args.seed, args.seconds,
                                                devices, mix)
         else:
-            arrivals = gen.generate(mix, args.seconds, args.seed,
-                                    shape["vocab_size"])
+            arrivals = gen.generate(mix, args.seconds, args.seed, vocab)
             plens = [len(a.prompt) for a in arrivals]
             harness.warm_up(engines(server), harness.warm_requests(
                 engines(server)[0], plens,
-                max(len(a.prompt) + a.max_new for a in arrivals)),
-                shape["vocab_size"])
+                max(len(a.prompt) + a.max_new for a in arrivals)), vocab)
         reqs, stats, steps, facts = harness.measure(
             server, arrivals, args.seconds, False, clock, drain_limit=0.0)
         for eng in engines(server):
             eng.take_pending()
             eng.abort()
         getattr(server, "queue", []).clear()
-        rec = harness.record(cell, shape, engines(server)[0].decode_quantum,
-                             args.seconds, {}, stats, steps, facts)
+        rec = harness.record(cell, server, args.seconds, {}, stats, steps,
+                             facts)
         row = {"rate_rps": rate, "sent": facts["sent"],
                "unstarted_at_close": facts["unstarted_at_close"],
                "compiles_in_window": facts["compiles_in_window"],
@@ -83,6 +82,8 @@ def main(argv=None) -> None:
         row["decode.step_ms"] = manifest.reader("decode.step_ms")(rec)
         row["engine.prefill_share"] = manifest.reader(
             "engine.prefill_share")(rec)
+        row["router.tier_load_ratio"] = manifest.reader(
+            "router.tier_load_ratio")(rec)
         print(json.dumps(row), flush=True)
 
 

@@ -10,6 +10,13 @@ permutation, and the gaps between arrivals are the same quantiles of an
 exponential distribution (a Poisson process's gaps), scaled to fill the
 window. The seed orders the requests and the gaps, and draws the token
 ids, uniform over the vocabulary.
+
+A mix may also give ``strata``, k: the order is then stratified, so
+that each block of k consecutive arrivals holds one request from each
+k-quantile of prompt length and one gap from each k-quantile of gap
+length (``stratified``). Every stretch of the window then carries the
+same share of the work, and a tail over the window depends less on where
+the seed happened to bunch long prompts or short gaps.
 """
 from __future__ import annotations
 
@@ -54,14 +61,34 @@ def work(mix: dict, seconds: float) -> list[tuple[int, int]]:
     return list(zip(p.tolist(), o.tolist()))
 
 
+def stratified(rng: np.random.Generator, n: int, k: int) -> np.ndarray:
+    """An order of 0..n-1 (indices ascending by size) made of blocks of
+    consecutive places, each block taking one index from each of at most
+    k strata of ceil(n / k) consecutive indices; where the top stratum is
+    short, the last blocks lack it and are one place shorter. The seed
+    deals each stratum over the blocks and orders each block."""
+    m = -(-n // k)
+    blocks = [[] for _ in range(m)]
+    for lo in range(0, n, m):
+        stratum = np.arange(lo, min(lo + m, n))
+        for b, i in enumerate(rng.permutation(stratum)):
+            blocks[b].append(int(i))
+    return np.asarray([i for blk in blocks for i in rng.permutation(blk)])
+
+
 def generate(mix: dict, seconds: float, seed: int,
              vocab: int) -> list[Arrival]:
     """The window's requests, sorted by due time."""
     pairs = work(mix, seconds)
     n = len(pairs)
     rng = np.random.default_rng(seed)
-    order = rng.permutation(n)
-    gaps = -np.log1p(-_quantiles(n))[rng.permutation(n)]
+    k = int(mix.get("strata", 0))
+    if k > 1:
+        order = stratified(rng, n, k)
+        gaps = -np.log1p(-_quantiles(n))[stratified(rng, n, k)]
+    else:
+        order = rng.permutation(n)
+        gaps = -np.log1p(-_quantiles(n))[rng.permutation(n)]
     # each request is due in the middle of its gap: all inside the window
     due = (np.cumsum(gaps) - gaps / 2) * (seconds / gaps.sum())
     out = []

@@ -15,7 +15,7 @@ ROOT = Path(__file__).resolve().parents[2]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from bench import manifest, serving  # noqa: E402
+from bench import manifest  # noqa: E402
 
 DATA = Path(__file__).resolve().parent / "data"
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
@@ -26,10 +26,13 @@ PER_LAYER = ("setup.compile_s", "engine.prefill_share", "decode.step_ms",
 
 
 def smoke_registry(monkeypatch) -> None:
-    """Serve the registry's smoke reductions (the tiny files' sizes)."""
-    from repro.configs import get_config, smoke_config
-    monkeypatch.setattr(serving, "get_config",
-                        lambda name: smoke_config(get_config(name)))
+    """Serve the registry's smoke reductions (the tiny files' sizes): the
+    model modules look the program's config up in ``repro.configs`` when
+    they are called."""
+    import repro.configs as registry
+    real = registry.get_config
+    monkeypatch.setattr(registry, "get_config",
+                        lambda name: registry.smoke_config(real(name)))
 
 
 def tiny_cell(config: str = "tiny-nemo", chips: int = 1,

@@ -28,7 +28,6 @@ def test_control_fails_where_the_program_passes(monkeypatch, config):
     one below it on the same requests."""
     import jax
     from bench import harness
-    from bench.serving import model_shape
     smoke_registry(monkeypatch)
     cell = tiny_cell(config)
     limit = cell.config["limits"]["max_logit_gap"]
@@ -37,7 +36,6 @@ def test_control_fails_where_the_program_passes(monkeypatch, config):
         server, arrivals = harness.prepare(cell, seed, 3.0, jax.devices())
         reqs, _, _, facts = harness.measure(server, arrivals, 3.0, False,
                                             clock)
-        gaps = harness.check(model_shape(cell.config), seed, reqs,
-                             controls=("fp8",))
+        gaps = harness.check(cell.config, seed, reqs, controls=("fp8",))
         assert facts["failed"] == 0
         assert gaps["program"] <= limit < gaps["controls"]["fp8"], gaps

@@ -61,11 +61,14 @@ def test_new_cell_mix_and_metric_from_files_alone(tmp_path):
     (tmp_path / "bench" / "configs").mkdir(parents=True)
     (tmp_path / "bench" / "traffic").mkdir()
     (tmp_path / "bench" / "metrics").mkdir()
+    (tmp_path / "bench" / "models").mkdir()
     conf = json.loads((ROOT / "bench" / "configs" /
                        "h2o-danube-1.8b.json").read_text())
-    conf["replicas"] = 4
+    conf["model"] = "windowed"
     (tmp_path / "bench" / "configs" / "danube-x4.json").write_text(
         json.dumps(conf))
+    (tmp_path / "bench" / "models" / "windowed.py").write_text(
+        "LEAF_IDS = {'w_window': 99}\n")
     (tmp_path / "bench" / "traffic" / "chat.json").write_text(
         (ROOT / "bench" / "traffic" / "chat.json").read_text())
     (tmp_path / "bench" / "traffic" / "chat-x4.json").write_text(
@@ -83,7 +86,9 @@ def test_new_cell_mix_and_metric_from_files_alone(tmp_path):
                         {"name": "paged_attn_roofline",
                          "moves": "ttft_p90_ms", "workloads": ["other"]}]}
     c = manifest.load_cell("danube.chat-pool4", bm, checkout=tmp_path)
-    assert c.chips == 4 and c.config["replicas"] == 4
+    assert c.chips == 4 and "replicas" not in c.config
+    assert manifest.model(c.config, tmp_path / "bench").LEAF_IDS == {
+        "w_window": 99}
     assert c.traffic["rate_rps"] == 9.5 and c.traffic["kind"] == "open_loop"
     assert [m["name"] for m in c.end_to_end] == ["setup_s", "ttft_p90_ms"]
     assert [m["name"] for m in c.per_layer] == ["router.tier_load_ratio"]

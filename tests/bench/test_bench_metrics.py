@@ -1,17 +1,18 @@
 """Metric arithmetic on hand-made records: percentiles over all requests,
-the window's token rate, and the roofline and MFU counts against values
-worked by hand."""
+the window's token rate, the roofline and MFU counts against values
+worked by hand, and the spread of decoding over a router's tiers."""
 import numpy as np
 import pytest
 
 import bench_tiny  # noqa: F401
-from bench import counts, manifest, stats
+from bench import manifest, stats
 from bench.harness import Record, ReqStat, StepStat, _keys
 
 SHAPE = {"hidden_size": 8, "num_attention_heads": 4,
          "num_key_value_heads": 2, "head_dim": 2, "intermediate_size": 16,
          "vocab_size": 32, "num_hidden_layers": 3}
 PEAKS = {"bf16_flops_per_s": 1000.0, "hbm_bytes_per_s": 100.0}
+PLAIN = manifest.model({})       # a configuration that names no module
 
 
 def _rec(requests, steps, window=10.0, trace=None, loop_end=10.5):
@@ -69,11 +70,11 @@ def test_keys_of_consecutive_tokens():
 
 def test_counts_by_hand():
     # per block: 2*8*4*2 (q, o) + 2*8*2*2 (k, v) + 3*8*16 = 128+64+384
-    assert counts.layer_params(SHAPE) == 576
-    assert counts.attention_flops(SHAPE, 10) == 4 * 10 * 4 * 2 * 3
+    assert PLAIN.layer_params(SHAPE) == 576
+    assert PLAIN.attention_flops(SHAPE, 10) == 4 * 10 * 4 * 2 * 3
     # 10 keys: 10*2*2*2*2 B of K and V; 2 tokens: 2*2*4*2*2 B of q and o
-    assert counts.decode_attention_bytes(SHAPE, 10, 2) == 3 * (160 + 64)
-    assert counts.model_flops(SHAPE, 2, 10, 1) == (
+    assert PLAIN.decode_attention_bytes(SHAPE, 10, 2) == 3 * (160 + 64)
+    assert PLAIN.model_flops(SHAPE, 2, 10, 1) == (
         2 * 576 * 3 * 2 + 4 * 10 * 4 * 2 * 3 + 2 * 8 * 32)
 
 
@@ -83,11 +84,11 @@ def test_roofline_and_mfu_by_hand():
     steps = [StepStat(0.0, 2.0, 1.5, 1, emitted=3, decode_tokens=2,
                       decode_keys=10, prefill_tokens=4, prefill_keys=10)]
     rec = _rec([], steps, trace=T())
-    least = max(counts.decode_attention_bytes(SHAPE, 10, 2) / 100.0,
-                counts.attention_flops(SHAPE, 10) / 1000.0)
+    least = max(PLAIN.decode_attention_bytes(SHAPE, 10, 2) / 100.0,
+                PLAIN.attention_flops(SHAPE, 10) / 1000.0)
     assert manifest.reader("paged_attn_roofline")(rec) == pytest.approx(
         100 * least / 2.0)
-    flops = counts.model_flops(SHAPE, 6, 20, 3)
+    flops = PLAIN.model_flops(SHAPE, 6, 20, 3)
     assert manifest.reader("step_mfu")(rec) == pytest.approx(
         100 * flops / (2.0 * 2 * 1000.0))
 
@@ -117,3 +118,34 @@ def test_trace_metrics_need_a_trace():
                              decode_keys=1)])
     assert manifest.reader("paged_attn_roofline")(rec) is None
     assert manifest.reader("device.idle_share")(rec) is None
+
+
+def _tiers(decoded: list[dict], tiers=("t0", "t1", "t2", "t3")):
+    steps = [StepStat(float(i), float(i) + 0.5, 0.4, len(d), per_tier=d)
+             for i, d in enumerate(decoded)]
+    rec = _rec([], steps)
+    rec.tiers = tiers
+    return manifest.reader("router.tier_load_ratio")(rec)
+
+
+def test_tier_load_ratio_of_even_tiers_is_one():
+    assert _tiers([{"t0": 8, "t1": 8, "t2": 8, "t3": 8},
+                   {"t0": 4, "t1": 4, "t2": 4, "t3": 4}]) == pytest.approx(1)
+
+
+def test_tier_load_ratio_counts_a_tier_that_never_stepped():
+    # one tier of four idle: max 8 over mean 6
+    assert _tiers([{"t0": 8, "t1": 8, "t2": 8},
+                   {"t0": 8, "t1": 8, "t2": 8}]) == pytest.approx(4 / 3)
+    # steps after the window's close do not count
+    late = StepStat(10.0, 10.4, 0.3, 1, per_tier={"t3": 99})
+    rec = _rec([], [StepStat(0.0, 1.0, 0.5, 2, per_tier={"t0": 6, "t1": 2}),
+                    late])
+    rec.tiers = ("t0", "t1")
+    assert manifest.reader("router.tier_load_ratio")(rec) == pytest.approx(
+        1.5)
+
+
+def test_tier_load_ratio_of_one_engine_is_none():
+    assert _tiers([{"": 8}, {"": 4}], tiers=()) is None
+    assert _tiers([{}], tiers=("t0", "t1")) is None
